@@ -33,6 +33,7 @@ import jax
 import numpy as np
 
 from repro.core import jackson
+from repro.kernels import default_interpret
 from repro.scenario import ScenarioSuite
 from repro.sim import simulate_stats_lanes
 
@@ -102,7 +103,7 @@ def run(scale: int = 1, m: int = 132, lanes: int = 6,
         elif ref_us is not None and backend == "batched":
             derived += f"_speedup_vs_reference={ref_us / us:.2f}x"
         elif backend == "pallas":
-            derived += f"_interpret={jax.default_backend() != 'tpu'}"
+            derived += f"_interpret={default_interpret()}"
             if ref_small is not None:
                 bitwise = all(
                     np.array_equal(np.asarray(getattr(ref_small, f)),

@@ -1,45 +1,28 @@
-"""Compatibility shims across the jax versions this repo runs on.
+"""Mesh and ``shard_map`` helpers shared by every sharded program (jax 0.9).
 
-The container pins an older jax (0.4.x) than some of the sharding helpers
-were written against; everything version-dependent funnels through here so
-call sites stay clean:
-
-  * ``jax.sharding.AxisType`` and the ``axis_types=`` kwarg of
-    ``jax.make_mesh`` only exist in newer jax.  :func:`make_mesh` forwards
-    them when available and silently builds a plain mesh otherwise (older
-    jax meshes are implicitly all-auto, which is exactly what the
-    ``Auto``-typed call sites request).
-  * ``shard_map`` moved from ``jax.experimental.shard_map`` to ``jax`` (and
-    dropped ``check_rep``).  :func:`shard_map` calls whichever exists.
+  * :func:`make_mesh` builds a mesh whose axes are all ``AxisType.Auto``
+    (the compiler propagates shardings, as every call site expects).
+  * :func:`shard_map` calls ``jax.shard_map`` with ``check_vma=False``.
 """
 from __future__ import annotations
 
 import jax
 
-HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-
 
 def shard_map(f, mesh, *, in_specs, out_specs):
-    """``jax.shard_map`` on new jax, ``jax.experimental.shard_map`` on 0.4.x.
+    """``jax.shard_map`` with the varying-manual-axes check off.
 
-    The 0.4.x path passes ``check_rep=False``: the repo's sharded programs
-    are strictly lane-local (no collectives), which the replication checker
-    of that era mis-handles around closed-over constants inside ``scan``.
+    The repo's sharded programs are strictly lane-local (no collectives):
+    a lane-local ``scan`` carry initialised from a closed-over constant is
+    unvarying on entry and lane-varying on exit, which ``check_vma=True``
+    rejects although no value crosses a lane.
     """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
-def make_mesh(shape, axis_names, *, auto_axes: bool = True):
-    """``jax.make_mesh`` with ``axis_types=(AxisType.Auto, ...)`` on jax
-    versions that support it, and a plain mesh on those that don't."""
-    if HAS_AXIS_TYPE and auto_axes:
-        return jax.make_mesh(
-            shape, axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(shape, axis_names)
+def make_mesh(shape, axis_names):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(
+        shape, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))

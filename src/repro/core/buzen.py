@@ -41,15 +41,17 @@ float64.
 from __future__ import annotations
 # contract: padded-n — reductions here are on the bitwise padding contract
 
+import math
 import os
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.scipy.special import gammaln, logsumexp
+import numpy as np
+from jax.scipy.special import logsumexp
 
 from . import numerics  # noqa: F401  (enables x64)
-from .numerics import NEG_INF, seqsum
+from .numerics import NEG_INF, seqcumsum, seqsum
 
 _BACKENDS = ("jnp", "pallas")
 # contract: allow(env-read): import-time default only — set_backend() overrides it at runtime, nothing caches the value
@@ -315,11 +317,39 @@ def _geometric_series(log_rho: jax.Array, m_max: int) -> jax.Array:
     return jnp.where(k == 0, 0.0, k * log_rho)
 
 
+def _log_factorials(m_max: int) -> np.ndarray:
+    """``[log k! for k in 0..m_max]``, a float64 constant of the trace.
+
+    The table depends on the static ``m_max`` only, so no ``lgamma`` is
+    traced: the TPU has no float64 unit and XLA's emulated float64
+    ``lgamma`` costs seconds of compilation per use.
+    """
+    return np.array([math.lgamma(k + 1.0) for k in range(m_max + 1)])
+
+
 def _poisson_series(log_load: jax.Array, m_max: int) -> jax.Array:
     """``[k log_load - log k! for k in 0..m_max]`` — series of an IS station
     (``k = 0`` pinned as in :func:`_geometric_series`)."""
     k = jnp.arange(m_max + 1)
-    return jnp.where(k == 0, 0.0, k * log_load - gammaln(k + 1.0))
+    return jnp.where(k == 0, 0.0, k * log_load - _log_factorials(m_max))
+
+
+def _log_multisets(count: jax.Array, m_max: int,
+                   dtype=jnp.float64) -> jax.Array:
+    """``[log C(j + count - 1, j) for j in 0..m_max]`` (``[..., m_max + 1]``
+    for ``count`` of shape ``[...]``).
+
+    That is ``lgamma(j + count) - lgamma(j + 1) - lgamma(count)``, formed
+    as the left-to-right running sum of ``log((count + i) / (1 + i))`` over
+    ``i < j``: no ``lgamma`` is traced (see :func:`_log_factorials`), and
+    the large ``lgamma(count)`` terms never have to cancel.  ``count = 1``
+    gives exactly ``0`` (each step is ``log(1 + i) - log(1 + i)``);
+    ``count = 0`` gives ``-inf`` from ``j = 1`` on.
+    """
+    cnt = jnp.asarray(count, dtype=dtype)[..., None]
+    i = jnp.arange(m_max, dtype=dtype)
+    steps = jnp.log(cnt + i) - jnp.log(1.0 + i)
+    return jnp.concatenate([jnp.zeros_like(cnt), seqcumsum(steps)], axis=-1)
 
 
 def _negbinom_series(log_rho: jax.Array, count: jax.Array,
@@ -334,15 +364,13 @@ def _negbinom_series(log_rho: jax.Array, count: jax.Array,
         coef[j] = j log_rho + lgamma(j + count) - lgamma(j + 1) - lgamma(count)
 
     ``count = 0`` (a padded class) makes every ``j >= 1`` coefficient
-    ``-inf`` (``lgamma(0) = +inf``), and the ``j = 0`` term is pinned to
-    exactly ``0`` — the convolution identity, mirroring the load-0 pin of
-    :func:`_geometric_series`.  ``count = 1`` reduces to the geometric
-    series exactly (the lgamma terms cancel).
+    ``-inf`` (see :func:`_log_multisets`), and the ``j = 0`` term is pinned
+    to exactly ``0`` — the convolution identity, mirroring the load-0 pin
+    of :func:`_geometric_series`.  ``count = 1`` reduces to the geometric
+    series exactly.
     """
     j = jnp.arange(m_max + 1)
-    cnt = jnp.asarray(count, dtype=jnp.float64)
-    lw = gammaln(j + cnt) - gammaln(j + 1.0) - gammaln(cnt)
-    return jnp.where(j == 0, 0.0, j * log_rho + lw)
+    return jnp.where(j == 0, 0.0, j * log_rho + _log_multisets(count, m_max))
 
 
 def log_normalizing_constants(

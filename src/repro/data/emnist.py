@@ -5,7 +5,7 @@ Resolution order:
 
   1. a **local cache**: an ``.npz`` file with arrays ``x`` (``[N, 28, 28]``
      or ``[N, 28, 28, 1]``, uint8 or float) and ``y`` (``[N]`` integer
-     labels) at ``$REPRO_EMNIST_PATH`` or ``~/.cache/repro/emnist.npz`` —
+     labels) at ``$REPRO_EMNIST_PATH`` or ``<checkout>/data/emnist.npz`` —
      e.g. a converted EMNIST-Balanced split dropped in by the user;
   2. a **deterministic synthetic fallback** with exactly the EMNIST tensor
      format (28x28 grayscale, float32 in [0, 1], int32 labels): the
@@ -25,6 +25,7 @@ from typing import Optional
 
 import numpy as np
 
+from .. import CHECKOUT_ROOT
 from .synthetic import ImageDataset, make_synthetic_image_dataset
 
 _IMAGE_SIZE = 28
@@ -33,11 +34,8 @@ _FALLBACK_SEED_OFFSET = 0xE3157  # "emnist"-namespace: differ from synthetic
 
 def emnist_cache_path() -> str:
     """The resolved local cache location (the file need not exist)."""
-    env = os.environ.get("REPRO_EMNIST_PATH")
-    if env:
-        return env
-    return os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                        "emnist.npz")
+    return (os.environ.get("REPRO_EMNIST_PATH")
+            or os.path.join(CHECKOUT_ROOT, "data", "emnist.npz"))
 
 
 def _subsample(x: np.ndarray, y: np.ndarray, num_classes: int,

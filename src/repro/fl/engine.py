@@ -375,9 +375,7 @@ class DeviceTrainer:
                     lambda v: v * factor.astype(v.dtype), g)
             if use_fused:
                 from ..kernels.fused_update import fused_async_update
-                interpret = jax.default_backend() != "tpu"
-                new, _ = fused_async_update(params, g, scale,
-                                            interpret=interpret)
+                new, _ = fused_async_update(params, g, scale)
                 return new
             # final astype guards the scan carry: any residual promotion
             # would flip the params pytree to f64 between iterations
@@ -476,7 +474,12 @@ class DeviceTrainer:
 
             if has_test:
                 final_loss, final_acc = evaluate(paramsK)
-                snap_losses, snap_accs = jax.vmap(evaluate)(grid_snaps)
+                # one grid point at a time: under the lane vmap each eval
+                # is then a single-batch-axis program whatever the lane
+                # count, so an f32 model evaluates bitwise alike alone and
+                # coalesced (a [lanes, G] batch axis changes XLA:CPU's f32
+                # dot blocking with the lane count)
+                snap_losses, snap_accs = jax.lax.map(evaluate, grid_snaps)
             else:
                 final_loss = final_acc = jnp.zeros(())
                 snap_losses = snap_accs = jnp.zeros((G,))

@@ -20,7 +20,9 @@ class Model(NamedTuple):
 
 def _dense_init(rng, fan_in, fan_out):
     k1, _ = jax.random.split(rng)
-    scale = np.sqrt(2.0 / fan_in)
+    # a Python float is weakly typed: the f32 draw stays f32 under x64,
+    # where a NumPy float64 scale would promote the weights to f64
+    scale = float(np.sqrt(2.0 / fan_in))
     return {"w": jax.random.normal(k1, (fan_in, fan_out), jnp.float32) * scale,
             "b": jnp.zeros((fan_out,), jnp.float32)}
 
@@ -51,10 +53,11 @@ def cnn_classifier(image_size: int, num_classes: int,
     def init(rng):
         k1, k2, k3 = jax.random.split(rng, 3)
         c1, c2 = channels
-        w1 = jax.random.normal(k1, (kernel, kernel, 1, c1), jnp.float32) * np.sqrt(
-            2.0 / (kernel * kernel))
-        w2 = jax.random.normal(k2, (kernel, kernel, c1, c2), jnp.float32) * np.sqrt(
-            2.0 / (kernel * kernel * c1))
+        w1 = jax.random.normal(k1, (kernel, kernel, 1, c1),
+                               jnp.float32) * float(np.sqrt(2.0 / (kernel * kernel)))
+        w2 = jax.random.normal(k2, (kernel, kernel, c1, c2),
+                               jnp.float32) * float(np.sqrt(
+                                   2.0 / (kernel * kernel * c1)))
         # SAME conv twice, then 2x2 pool
         flat = (image_size // 2) * (image_size // 2) * c2
         return {

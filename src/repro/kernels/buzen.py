@@ -9,17 +9,19 @@ layout:
 
   * grid ``(B, n_stations)``: batch rows are independent (``parallel``
     semantics), stations run the sequential recursion (``arbitrary``);
-  * the running log-constant row ``U[0..m]`` lives in VMEM scratch across
-    the station axis, initialized from the aggregated infinite-server
-    Poisson factor at station 0 of each row;
+  * the running log-constant row ``U[0..m]`` lives in a ``(1, W)`` VMEM
+    scratch across the station axis (``W`` = ``m + 1`` rounded up to whole
+    128-lane vregs), initialized from the aggregated infinite-server
+    Poisson factor at station 0 of each row; the per-row loads sit in SMEM;
   * each station performs the log-space truncated convolution
     ``U'[m] = logsumexp_k (k * log_rho_i + U[m - k])`` as a single
-    ``(m+1, m+1)`` masked reduction in VMEM (m ~ O(100), so ~64 KB).
+    ``(W, W)`` masked reduction in VMEM (256 KB at m = 132), its shift
+    matrix built by one strided lane roll rather than a gather.
 
 Public entry points:
 
   * :func:`buzen_pallas_batched` — raw float32 kernel, ``[B, S] -> [B, m+1]``;
-    compiled when running on TPU, interpret fallback elsewhere.
+    compiled on a TPU, interpreted on any other backend.
   * :func:`buzen_log_Z_batched` — differentiable wrapper: float32 Pallas
     forward, VJP through the float64 ``jnp`` reference DP (the kernel itself
     has no autodiff rule), so the batched optimizer can run on this backend.
@@ -37,48 +39,93 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from . import BLOCK0, default_interpret
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
+NEG_INF = -1e30
+LANES = 128
+
+
+def _padded_width(m_max: int) -> int:
+    """Lane width of the DP row: ``m_max + 1`` rounded up to whole vregs."""
+    return -(-(m_max + 1) // LANES) * LANES
+
+
+def _log_conv_row(u, coef_col, width: int):
+    """``U'[m] = logsumexp_{k <= m} (coef[k] + U[m - k])`` on one row.
+
+    ``u`` is the ``(1, width)`` running row and ``coef_col`` the
+    ``(width, 1)`` station series (or anything broadcasting to it).  The
+    shift matrix ``toe[k, m] = U[m - k]`` is one strided lane roll of the
+    broadcast row (row ``k`` rolled by ``k``), so the convolution is a
+    masked column reduction with no in-kernel gather.  Entries past
+    ``m_max`` only ever feed columns past ``m_max``, which the wrapper
+    slices off.
+    """
+    kk = jax.lax.broadcasted_iota(jnp.int32, (width, width), 0)
+    mm = jax.lax.broadcasted_iota(jnp.int32, (width, width), 1)
+    toe = pltpu.roll(jnp.broadcast_to(u, (width, width)), BLOCK0, 1,
+                     stride=1, stride_axis=0)
+    terms = jnp.where(kk <= mm, coef_col + toe, np.float32(NEG_INF))
+    col_max = jnp.max(terms, axis=0, keepdims=True)
+    # contract: allow(raw-reduction): logsumexp over the m-convolution axis within ONE station — the client/station axis is the kernel's sequential grid loop, and this f32 path is rtol-validated, not bitwise
+    sumexp = jnp.sum(jnp.exp(terms - col_max), axis=0, keepdims=True)
+    return col_max + jnp.log(sumexp)
 
 
 def _buzen_kernel(rho_ref, init_ref, out_ref, u_scr, *, n_stations: int,
-                  m_pad: int):
+                  width: int):
     i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _init():
-        u_scr[...] = init_ref[0]  # aggregated IS Poisson factor row
+        u_scr[...] = init_ref[...]  # aggregated IS Poisson factor row
 
-    log_rho = rho_ref[0, 0]
-    u = u_scr[...]  # [m_pad]
-    # T[m, k] = k * log_rho + U[m - k], masked to k <= m
-    mm = jax.lax.broadcasted_iota(jnp.int32, (m_pad, m_pad), 0)
-    kk = jax.lax.broadcasted_iota(jnp.int32, (m_pad, m_pad), 1)
-    valid = kk <= mm
-    shifted = jnp.where(valid, (mm - kk), 0)
-    terms = jnp.where(valid, kk.astype(jnp.float32) * log_rho
-                      + jnp.take_along_axis(
-                          jnp.broadcast_to(u[None, :], (m_pad, m_pad)),
-                          shifted, axis=1), NEG_INF)
-    row_max = jnp.max(terms, axis=1)
-    # contract: allow(raw-reduction): logsumexp over the m-convolution axis within ONE station — the client/station axis is the kernel's sequential grid loop, and this f32 path is rtol-validated, not bitwise
-    sumexp = jnp.sum(jnp.exp(terms - row_max[:, None]), axis=1)
-    new_u = row_max + jnp.log(sumexp)
-    u_scr[...] = new_u
+    # geometric series of one single-server station: coef[k] = k log_rho
+    k_col = jax.lax.broadcasted_iota(jnp.int32, (width, 1), 0)
+    u_scr[...] = _log_conv_row(u_scr[...],
+                               k_col.astype(jnp.float32) * rho_ref[0, i], width)
 
     @pl.when(i == n_stations - 1)
     def _finalize():
-        out_ref[0] = u_scr[...]
+        out_ref[...] = u_scr[...]
 
 
-def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _init_rows(log_gamma_total, m_max: int, width: int):
+    """``[B, 1, width]`` float32 Poisson factor rows of the aggregated IS
+    stations (``k log gamma - log k!``), ``NEG_INF`` past ``m_max``.
+
+    Both terms reach hundreds at ``k ~ m_max`` and mostly cancel, so the
+    row is formed in the input's precision and rounded once to float32."""
+    from ..core.buzen import _log_factorials
+
+    k = jnp.arange(width, dtype=log_gamma_total.dtype)
+    rows = (k[None, :] * log_gamma_total[:, None]
+            - _log_factorials(width - 1).astype(k.dtype))
+    rows = jnp.where(k <= m_max, rows, NEG_INF)
+    return rows.astype(jnp.float32)[:, None, :]
+
+
+def _dp_call(kernel, coef, init_rows, coef_spec, grid, width: int,
+             interpret: bool):
+    """The shared ``pallas_call`` of both Buzen kernels: one ``(1, width)``
+    row per batch entry, stations on the sequential grid axis."""
+    B = init_rows.shape[0]
+    row = pl.BlockSpec((None, 1, width), lambda b, i: (b, BLOCK0, BLOCK0))
+    return pl.pallas_call(
+        kernel,
+        grid=grid,
+        in_specs=[coef_spec, row],
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((B, 1, width), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((1, width), jnp.float32)],
+        interpret=interpret,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+    )(coef, init_rows)
 
 
 @functools.partial(jax.jit, static_argnames=("m_max", "interpret"))
@@ -94,33 +141,20 @@ def buzen_pallas_batched(log_rho: jax.Array, log_gamma_total: jax.Array,
     """
     interp = default_interpret() if interpret is None else interpret
     B, n = log_rho.shape
-    m_pad = m_max + 1
-    k = jnp.arange(m_pad, dtype=jnp.float32)
-    from jax.scipy.special import gammaln
-    init_rows = (k[None, :] * log_gamma_total[:, None].astype(jnp.float32)
-                 - gammaln(k + 1.0)[None, :]).astype(jnp.float32)
+    width = _padded_width(m_max)
     # load-0 stations (padded clients under the traced-n convention) arrive
     # as log_rho = -inf; clamp to the finite mask value so the kernel's
     # k * log_rho products stay NaN-free — the k >= 1 terms then underflow
     # to exactly 0 in the row logsumexp, making the station a convolution
     # identity, matching the jnp reference's masked geometric series
     rho32 = jnp.maximum(log_rho.astype(jnp.float32), NEG_INF)
-
-    kernel = functools.partial(_buzen_kernel, n_stations=n, m_pad=m_pad)
-    return pl.pallas_call(
-        kernel,
-        grid=(B, n),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b, i: (b, i)),
-            pl.BlockSpec((1, m_pad), lambda b, i: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, m_pad), lambda b, i: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, m_pad), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((m_pad,), jnp.float32)],
-        interpret=interp,
-        compiler_params=None if interp else _CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-    )(rho32, init_rows)
+    kernel = functools.partial(_buzen_kernel, n_stations=n, width=width)
+    out = _dp_call(kernel, rho32[:, None, :],
+                   _init_rows(log_gamma_total, m_max, width),
+                   pl.BlockSpec((None, 1, n), lambda b, i: (b, BLOCK0, BLOCK0),
+                                memory_space=pltpu.SMEM),
+                   (B, n), width, interp)
+    return out[:, 0, :m_max + 1]
 
 
 def _reference_log_Z(log_rho: jax.Array, log_gamma_total: jax.Array,
@@ -189,7 +223,7 @@ def buzen_pallas(log_rho: jax.Array, log_gamma_total: jax.Array, m_max: int,
 # ---------------------------------------------------------------------------
 
 def _buzen_classes_kernel(series_ref, init_ref, out_ref, u_scr, *,
-                          n_stations: int, m_pad: int):
+                          n_stations: int, width: int):
     """Station ``i`` convolves the running row with a PRECOMPUTED series.
 
     Identical control flow to :func:`_buzen_kernel`, but the station factor
@@ -202,28 +236,20 @@ def _buzen_classes_kernel(series_ref, init_ref, out_ref, u_scr, *,
 
     @pl.when(i == 0)
     def _init():
-        u_scr[...] = init_ref[0]  # aggregated IS Poisson factor row
+        u_scr[...] = init_ref[...]  # aggregated IS Poisson factor row
 
-    series = series_ref[0, 0]  # [m_pad] class series coefficients
-    u = u_scr[...]
-    # T[m, k] = series[k] + U[m - k], masked to k <= m
-    mm = jax.lax.broadcasted_iota(jnp.int32, (m_pad, m_pad), 0)
-    kk = jax.lax.broadcasted_iota(jnp.int32, (m_pad, m_pad), 1)
-    valid = kk <= mm
-    shifted = jnp.where(valid, (mm - kk), 0)
-    terms = jnp.where(valid,
-                      jnp.broadcast_to(series[None, :], (m_pad, m_pad))
-                      + jnp.take_along_axis(
-                          jnp.broadcast_to(u[None, :], (m_pad, m_pad)),
-                          shifted, axis=1), NEG_INF)
-    row_max = jnp.max(terms, axis=1)
-    # contract: allow(raw-reduction): logsumexp over the m-convolution axis within ONE class station — the class axis is the kernel's sequential grid loop, and this f32 path is rtol-validated, not bitwise
-    sumexp = jnp.sum(jnp.exp(terms - row_max[:, None]), axis=1)
-    u_scr[...] = row_max + jnp.log(sumexp)
+    # the (1, width) series row as a (width, 1) column: a diagonal select
+    # and lane reduction (one non-zero term per row, so exact)
+    kk = jax.lax.broadcasted_iota(jnp.int32, (width, width), 0)
+    mm = jax.lax.broadcasted_iota(jnp.int32, (width, width), 1)
+    # contract: allow(raw-reduction): one-hot diagonal extraction — exactly one non-zero term per row, bitwise under any association
+    series_col = jnp.sum(jnp.where(kk == mm, series_ref[...], np.float32(0)),
+                         axis=1, keepdims=True)
+    u_scr[...] = _log_conv_row(u_scr[...], series_col, width)
 
     @pl.when(i == n_stations - 1)
     def _finalize():
-        out_ref[0] = u_scr[...]
+        out_ref[...] = u_scr[...]
 
 
 @functools.partial(jax.jit, static_argnames=("m_max", "interpret"))
@@ -239,48 +265,37 @@ def buzen_classes_pallas_batched(log_rho: jax.Array, counts: jax.Array,
     infinite-server log-loads.  Each grid step folds a whole class through
     its negative-binomial generating series
 
-        ``coef[j] = j log_rho + lgamma(j + count) - lgamma(j + 1)
-                    - lgamma(count)``
+        ``coef[j] = j log_rho + log C(j + count - 1, j)``
 
-    precomputed on the host in float32 (``j = 0`` pinned to ``0``;
-    ``count = 0`` padded classes clamp to the mask value, making them
-    exact convolution identities as in the ``jnp`` DP).  Returns float32
-    ``[B, m_max + 1]``.  Forward-only — differentiate through
+    precomputed outside the kernel in the input's precision and rounded
+    once to float32 (``j = 0`` pinned to ``0``; ``count = 0`` padded
+    classes clamp to the mask value, making them exact convolution
+    identities as in the ``jnp`` DP).  Returns float32 ``[B, m_max + 1]``.
+    Forward-only — differentiate through
     :func:`buzen_classes_log_Z_batched`.
     """
-    from jax.scipy.special import gammaln
+    from ..core.buzen import _log_multisets
 
     interp = default_interpret() if interpret is None else interpret
     B, S = log_rho.shape
-    m_pad = m_max + 1
-    k = jnp.arange(m_pad, dtype=jnp.float32)
-    init_rows = (k[None, :] * log_gamma_total[:, None].astype(jnp.float32)
-                 - gammaln(k + 1.0)[None, :]).astype(jnp.float32)
-    cnt = counts.astype(jnp.float32)
-    lw = (gammaln(k[None, None, :] + cnt[:, :, None])
-          - gammaln(k + 1.0)[None, None, :]
-          - gammaln(cnt)[:, :, None])
-    lr32 = jnp.maximum(log_rho.astype(jnp.float32), NEG_INF)
-    series = k[None, None, :] * lr32[:, :, None] + lw
+    width = _padded_width(m_max)
+    k = jnp.arange(width, dtype=log_rho.dtype)
+    lr = jnp.maximum(log_rho, NEG_INF)
+    series = (k[None, None, :] * lr[:, :, None]
+              + _log_multisets(counts, width - 1, log_rho.dtype))
     series = jnp.where(k[None, None, :] == 0, 0.0,
                        jnp.maximum(series, NEG_INF))
+    # terms past m_max only reach columns past m_max: pin them finite
+    series = jnp.where(k <= m_max, series, NEG_INF).astype(jnp.float32)
+    series = series[:, :, None, :]
 
     kernel = functools.partial(_buzen_classes_kernel, n_stations=S,
-                               m_pad=m_pad)
-    return pl.pallas_call(
-        kernel,
-        grid=(B, S),
-        in_specs=[
-            pl.BlockSpec((1, 1, m_pad), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, m_pad), lambda b, i: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, m_pad), lambda b, i: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, m_pad), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((m_pad,), jnp.float32)],
-        interpret=interp,
-        compiler_params=None if interp else _CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-    )(series, init_rows)
+                               width=width)
+    out = _dp_call(kernel, series, _init_rows(log_gamma_total, m_max, width),
+                   pl.BlockSpec((None, None, 1, width),
+                                lambda b, i: (b, i, BLOCK0, BLOCK0)),
+                   (B, S), width, interp)
+    return out[:, 0, :m_max + 1]
 
 
 def _reference_class_log_Z(log_rho: jax.Array, counts: jax.Array,

@@ -41,8 +41,11 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from . import BLOCK0, default_interpret
 from ..core import events as E
 from ..core.buzen import NetworkParams
 from ..core.numerics import seqsum
@@ -51,14 +54,24 @@ from ..scenario.laws import get_law
 _BIG_SEQ = E._BIG_SEQ
 
 
-def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+# Constants inside the kernels are typed int32 / table-dtype explicitly: with
+# x64 on, a bare Python int or float is a weak 64-bit value, which Mosaic
+# cannot lower.  Integer reductions are max/min, never a sum (``jnp.sum``
+# over int32 accumulates in int64 under x64).
+_I32 = {name: np.int32(getattr(E, name))
+        for name in ("DOWN", "COMP_WAIT", "COMP_SERV", "UP", "CS_WAIT",
+                     "CS_SERV")}
 
 
 def _first_index_min(values, idx, size: int):
     """First index attaining ``min(values)`` — the TPU-friendly argmin."""
     v_min = jnp.min(values)
-    return v_min, jnp.min(jnp.where(values == v_min, idx, size))
+    return v_min, jnp.min(jnp.where(values == v_min, idx, np.int32(size)))
+
+
+def _any(mask):
+    """``mask.any()`` as an int32 max reduction."""
+    return jnp.max(mask.astype(jnp.int32)) > 0
 
 
 def _one_event(tbl, mu_c, mu_u, rand, idx, cli, *,
@@ -76,15 +89,16 @@ def _one_event(tbl, mu_c, mu_u, rand, idx, cli, *,
     """
     finish, phase, client, seq, disp = tbl
     e_up, e_comp, svc_down, svc_cs, c_new, seq_ctr, rnd = rand
+    P = _I32
+    inf = np.array(np.inf, finish.dtype)
 
     def gather_i(table, j):
-        # x64 mode promotes integer sums to int64: pin the gather to i32
-        # contract: allow(raw-reduction): one-hot gather — exactly one non-zero term, bitwise under any association
-        return jnp.sum(jnp.where(idx == j, table, 0)).astype(jnp.int32)
+        # one-hot gather: exactly one slot matches, so the max is its value
+        return jnp.max(jnp.where(idx == j, table, np.int32(-_BIG_SEQ - 1)))
 
     def gather_rate(row, c):
-        # contract: allow(raw-reduction): one-hot gather — exactly one non-zero term, bitwise under any association
-        return jnp.sum(jnp.where(cli == c, row, 0.0))
+        # one-hot gather: exactly one client matches, so the max is its rate
+        return jnp.max(jnp.where(cli == c, row, -inf))
 
     # -- the completing slot (parallel argmin over the clock table) ---------
     t_new, j = _first_index_min(finish, idx, m_max)
@@ -93,63 +107,59 @@ def _one_event(tbl, mu_c, mu_u, rand, idx, cli, *,
     ph = gather_i(phase, j)
     delay = rnd - gather_i(disp, j)
 
-    is_down = ph == E.DOWN
-    is_comp = ph == E.COMP_SERV
-    is_up = ph == E.UP
-    is_cs = ph == E.CS_SERV
+    is_down = ph == P["DOWN"]
+    is_comp = ph == P["COMP_SERV"]
+    is_up = ph == P["UP"]
+    is_cs = ph == P["CS_SERV"]
     is_update = is_cs if has_cs else is_up
-    new_round = rnd + jnp.where(is_update, 1, 0).astype(jnp.int32)
+    new_round = rnd + is_update.astype(jnp.int32)
 
     svc_up = e_up / gather_rate(mu_u, c)
     svc_c = e_comp / gather_rate(mu_c, c)
 
     # -- fused phase promotion / routing of slot j --------------------------
     phase_j = jnp.where(
-        is_down, E.COMP_WAIT,
-        jnp.where(is_comp, E.UP, jnp.where(is_update, E.DOWN, E.CS_WAIT)))
+        is_down, P["COMP_WAIT"],
+        jnp.where(is_comp, P["UP"],
+                  jnp.where(is_update, P["DOWN"], P["CS_WAIT"])))
     finish_j = jnp.where(
         is_comp, t_new + svc_up,
-        jnp.where(is_update, t_new + svc_down, jnp.inf))
-    joins_fifo = is_down | (is_up & has_cs)
+        jnp.where(is_update, t_new + svc_down, inf))
+    joins_fifo = (is_down | is_up) if has_cs else is_down
     seq_j = jnp.where(joins_fifo, seq_ctr, gather_i(seq, j))
     new_seq_ctr = seq_ctr + joins_fifo.astype(jnp.int32)
     client_j = jnp.where(is_update, c_new, c)
     disp_j = jnp.where(is_update, new_round, gather_i(disp, j))
 
-    phase = jnp.where(onej, phase_j, phase).astype(jnp.int32)
+    phase = jnp.where(onej, phase_j, phase)
     finish = jnp.where(onej, finish_j, finish)
-    seq = jnp.where(onej, seq_j, seq).astype(jnp.int32)
-    client = jnp.where(onej, client_j, client).astype(jnp.int32)
-    disp = jnp.where(onej, disp_j, disp).astype(jnp.int32)
+    seq = jnp.where(onej, seq_j, seq)
+    client = jnp.where(onej, client_j, client)
+    disp = jnp.where(onej, disp_j, disp)
 
     # -- FIFO promotion at the compute station of client c ------------------
     promo_comp = is_down | is_comp
-    # contract: allow(raw-reduction): int32 indicator count over the [m_max] table — exact integer arithmetic, and the table axis is never padded-n
-    serving_c = jnp.sum(((phase == E.COMP_SERV) & (client == c))
-                        .astype(jnp.int32)) > 0
-    waiting_c = (phase == E.COMP_WAIT) & (client == c)
-    vals = jnp.where(waiting_c, seq, _BIG_SEQ)
+    serving_c = _any((phase == P["COMP_SERV"]) & (client == c))
+    waiting_c = (phase == P["COMP_WAIT"]) & (client == c)
+    vals = jnp.where(waiting_c, seq, np.int32(_BIG_SEQ))
     _, pick = _first_index_min(vals, idx, m_max)
-    # contract: allow(raw-reduction): int32 indicator count over the [m_max] table — exact integer arithmetic, and the table axis is never padded-n
-    any_wait = jnp.sum(waiting_c.astype(jnp.int32)) > 0
+    any_wait = _any(waiting_c)
     do_comp = promo_comp & ~serving_c & any_wait
     onep = (idx == pick) & do_comp
-    phase = jnp.where(onep, E.COMP_SERV, phase)
+    phase = jnp.where(onep, P["COMP_SERV"], phase)
     finish = jnp.where(onep, t_new + svc_c, finish)
 
     if has_cs:
         # -- FIFO promotion at the CS single-server queue -------------------
         promo_cs = is_up | is_cs
-        cs_waiting = phase == E.CS_WAIT
-        vals_cs = jnp.where(cs_waiting, seq, _BIG_SEQ)
+        cs_waiting = phase == P["CS_WAIT"]
+        vals_cs = jnp.where(cs_waiting, seq, np.int32(_BIG_SEQ))
         _, pick_cs = _first_index_min(vals_cs, idx, m_max)
-        # contract: allow(raw-reduction): int32 indicator count over the [m_max] table — exact integer arithmetic, and the table axis is never padded-n
-        cs_busy = jnp.sum((phase == E.CS_SERV).astype(jnp.int32)) > 0
-        # contract: allow(raw-reduction): int32 indicator count over the [m_max] table — exact integer arithmetic, and the table axis is never padded-n
-        any_cs_wait = jnp.sum(cs_waiting.astype(jnp.int32)) > 0
+        cs_busy = _any(phase == P["CS_SERV"])
+        any_cs_wait = _any(cs_waiting)
         do_cs = promo_cs & ~cs_busy & any_cs_wait
         onec = (idx == pick_cs) & do_cs
-        phase = jnp.where(onec, E.CS_SERV, phase)
+        phase = jnp.where(onec, P["CS_SERV"], phase)
         finish = jnp.where(onec, t_new + svc_cs, finish)
     else:
         do_cs = jnp.zeros((), jnp.bool_)
@@ -157,6 +167,57 @@ def _one_event(tbl, mu_c, mu_u, rand, idx, cli, *,
     desc = (t_new, j, c, is_update, delay, new_seq_ctr, new_round, ph,
             do_comp, do_cs)
     return (finish, phase, client, seq, disp), desc
+
+
+def _check_clock(dtype, interpret: bool) -> None:
+    """Refuse a float64 finish-clock table on the compiled path.
+
+    Mosaic has no float64, and the event engine keeps its clock in float64
+    (``repro.core.numerics`` turns x64 on).  Until the clock moves to a
+    32-bit representation the kernels compile only for a float32 table;
+    interpret mode runs either.
+    """
+    if not interpret and jnp.dtype(dtype) == jnp.float64:
+        raise NotImplementedError(
+            "the pallas event backend cannot compile for the TPU: the event "
+            "engine's finish-clock table is float64 and Mosaic has no "
+            "float64.  Use backend='batched' (plain XLA) on the TPU, or "
+            "interpret=True.")
+
+
+def _lane_call(kernel, tables, rates, scalars, out_widths, interpret: bool):
+    """One ``pallas_call`` over the ``K`` lanes of stacked task tables.
+
+    Every ``[K, w]`` operand is viewed as ``[K, 1, w]`` so that a lane's
+    ``(1, w)`` row block spans the whole of the last two dimensions (the
+    TPU's block rule).  Tables ``[K, m_max]`` and rates ``[K, n]`` go to
+    VMEM; the per-lane scalars in and the descriptors out live in SMEM.
+    ``out_widths`` are the widths of the float time and int32 descriptor
+    outputs.  Returns the five tables and the two outputs as ``[K, w]``.
+    """
+    finish = tables[0]
+    K, m_max = finish.shape
+    vmem = lambda w: pl.BlockSpec((None, 1, w),  # noqa: E731
+                                  lambda k: (k, BLOCK0, BLOCK0))
+    smem = lambda w: pl.BlockSpec((None, 1, w),  # noqa: E731
+                                  lambda k: (k, BLOCK0, BLOCK0),
+                                  memory_space=pltpu.SMEM)
+    args = [a[:, None, :] for a in tables + rates + scalars]
+    outs = pl.pallas_call(
+        kernel,
+        grid=(K,),
+        in_specs=[vmem(a.shape[-1]) for a in tables + rates]
+        + [smem(a.shape[-1]) for a in scalars],
+        out_specs=[vmem(m_max)] * 5 + [smem(w) for w in out_widths],
+        out_shape=[jax.ShapeDtypeStruct((K, 1, m_max), finish.dtype)]
+        + [jax.ShapeDtypeStruct((K, 1, m_max), jnp.int32)] * 4
+        + [jax.ShapeDtypeStruct((K, 1, out_widths[0]), finish.dtype),
+           jax.ShapeDtypeStruct((K, 1, out_widths[1]), jnp.int32)],
+        interpret=interpret,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+    )(*args)
+    return [o[:, 0, :] for o in outs]
 
 
 def _event_kernel(finish_ref, phase_ref, client_ref, seq_ref, disp_ref,
@@ -191,14 +252,14 @@ def _event_kernel(finish_ref, phase_ref, client_ref, seq_ref, disp_ref,
     o_t_ref[0, 0] = t_new
     o_int_ref[0, 0] = j
     o_int_ref[0, 1] = c
-    o_int_ref[0, 2] = jnp.where(is_update, 1, 0).astype(jnp.int32)
+    o_int_ref[0, 2] = is_update.astype(jnp.int32)
     o_int_ref[0, 3] = delay
     o_int_ref[0, 4] = new_seq_ctr
     o_int_ref[0, 5] = new_round
     # transition descriptors for the caller's O(1) occupancy maintenance
     o_int_ref[0, 6] = ph
-    o_int_ref[0, 7] = jnp.where(do_comp, 1, 0).astype(jnp.int32)
-    o_int_ref[0, 8] = jnp.where(do_cs, 1, 0).astype(jnp.int32)
+    o_int_ref[0, 7] = do_comp.astype(jnp.int32)
+    o_int_ref[0, 8] = do_cs.astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("has_cs", "interpret"))
@@ -216,27 +277,13 @@ def event_step_tables(finish, phase, client, seq, disp_round, mu_c, mu_u,
     ``[K, 9]``.
     """
     interp = default_interpret() if interpret is None else interpret
+    _check_clock(finish.dtype, interp)
     K, m_max = finish.shape
     n = mu_c.shape[1]
     kernel = functools.partial(_event_kernel, has_cs=has_cs, m_max=m_max,
                                n=n)
-    row = lambda w: pl.BlockSpec((1, w), lambda k: (k, 0))  # noqa: E731
-    return pl.pallas_call(
-        kernel,
-        grid=(K,),
-        in_specs=[row(m_max)] * 5 + [row(n)] * 2 + [row(4), row(3)],
-        out_specs=[row(m_max)] * 5 + [row(1), row(9)],
-        out_shape=[
-            jax.ShapeDtypeStruct((K, m_max), finish.dtype),
-            jax.ShapeDtypeStruct((K, m_max), jnp.int32),
-            jax.ShapeDtypeStruct((K, m_max), jnp.int32),
-            jax.ShapeDtypeStruct((K, m_max), jnp.int32),
-            jax.ShapeDtypeStruct((K, m_max), jnp.int32),
-            jax.ShapeDtypeStruct((K, 1), finish.dtype),
-            jax.ShapeDtypeStruct((K, 9), jnp.int32),
-        ],
-        interpret=interp,
-    )(finish, phase, client, seq, disp_round, mu_c, mu_u, fscal, iscal)
+    return _lane_call(kernel, (finish, phase, client, seq, disp_round),
+                      (mu_c, mu_u), (fscal, iscal), (1, 9), interp)
 
 
 def _megastep_kernel(finish_ref, phase_ref, client_ref, seq_ref, disp_ref,
@@ -278,7 +325,7 @@ def _megastep_kernel(finish_ref, phase_ref, client_ref, seq_ref, disp_ref,
                                 has_cs=has_cs, m_max=m_max, n=n)
         (t_new, j, c, is_update, delay, new_seq_ctr, new_round, ph,
          do_comp, do_cs) = desc
-        keep = i < rem
+        keep = np.int32(i) < rem
         if stop_on_update:
             keep = keep & ~done
             done = done | (keep & is_update)
@@ -288,15 +335,14 @@ def _megastep_kernel(finish_ref, phase_ref, client_ref, seq_ref, disp_ref,
         o_t_ref[0, i] = t_new
         o_int_ref[0, 10 * i + 0] = j
         o_int_ref[0, 10 * i + 1] = c
-        o_int_ref[0, 10 * i + 2] = jnp.where(is_update, 1, 0).astype(
-            jnp.int32)
+        o_int_ref[0, 10 * i + 2] = is_update.astype(jnp.int32)
         o_int_ref[0, 10 * i + 3] = delay
         o_int_ref[0, 10 * i + 4] = new_seq_ctr
         o_int_ref[0, 10 * i + 5] = new_round
         o_int_ref[0, 10 * i + 6] = ph
-        o_int_ref[0, 10 * i + 7] = jnp.where(do_comp, 1, 0).astype(jnp.int32)
-        o_int_ref[0, 10 * i + 8] = jnp.where(do_cs, 1, 0).astype(jnp.int32)
-        o_int_ref[0, 10 * i + 9] = jnp.where(keep, 1, 0).astype(jnp.int32)
+        o_int_ref[0, 10 * i + 7] = do_comp.astype(jnp.int32)
+        o_int_ref[0, 10 * i + 8] = do_cs.astype(jnp.int32)
+        o_int_ref[0, 10 * i + 9] = keep.astype(jnp.int32)
 
     finish, phase, client, seq, disp = tbl
     o_finish_ref[...] = finish
@@ -322,29 +368,15 @@ def megastep_tables(finish, phase, client, seq, disp_round, mu_c, mu_u,
     (the single-step 9 columns plus the ``keep`` mask per event).
     """
     interp = default_interpret() if interpret is None else interpret
+    _check_clock(finish.dtype, interp)
     K, m_max = finish.shape
     n = mu_c.shape[1]
     kernel = functools.partial(_megastep_kernel, has_cs=has_cs, m_max=m_max,
                                n=n, chunk=chunk,
                                stop_on_update=stop_on_update)
-    row = lambda w: pl.BlockSpec((1, w), lambda k: (k, 0))  # noqa: E731
-    return pl.pallas_call(
-        kernel,
-        grid=(K,),
-        in_specs=[row(m_max)] * 5 + [row(n)] * 2
-        + [row(4 * chunk), row(3 + chunk)],
-        out_specs=[row(m_max)] * 5 + [row(chunk), row(10 * chunk)],
-        out_shape=[
-            jax.ShapeDtypeStruct((K, m_max), finish.dtype),
-            jax.ShapeDtypeStruct((K, m_max), jnp.int32),
-            jax.ShapeDtypeStruct((K, m_max), jnp.int32),
-            jax.ShapeDtypeStruct((K, m_max), jnp.int32),
-            jax.ShapeDtypeStruct((K, m_max), jnp.int32),
-            jax.ShapeDtypeStruct((K, chunk), finish.dtype),
-            jax.ShapeDtypeStruct((K, 10 * chunk), jnp.int32),
-        ],
-        interpret=interp,
-    )(finish, phase, client, seq, disp_round, mu_c, mu_u, fscal, iscal)
+    return _lane_call(kernel, (finish, phase, client, seq, disp_round),
+                      (mu_c, mu_u), (fscal, iscal), (chunk, 10 * chunk),
+                      interp)
 
 
 # ---------------------------------------------------------------------------
@@ -358,14 +390,9 @@ def _lane_randomness(params: NetworkParams, state, distribution: str,
     padding-invariant inverse-CDF routing draw of
     ``repro.core.events._route_client``).
 
-    Routed through the ``chunk=1`` block draw: the single-step and
-    megastep streams must share one fusion structure, because XLA's
-    mul-add (FMA) contraction can differ between distinct fusion
-    contexts — op-identical draw code in a *different* surrounding
-    program is not enough for byte-equal floats (1-ulp divergence on the
-    lognormal's ``exp(normal - log(rate) - 0.5)`` chain).  A scan body is
-    its own fusion context, so the length-1 scan here contracts exactly
-    like the length-``E`` scan in :func:`_lane_chunk_randomness`.
+    Routed through the ``chunk=1`` block draw, so the single-step and
+    megastep streams share one draw program (see
+    :func:`_lane_chunk_randomness`).
     """
     chain, c_new, fscal = _lane_chunk_randomness(params, state, distribution,
                                                  has_cs, 1)
@@ -523,23 +550,34 @@ def _lane_chunk_randomness(params: NetworkParams, state, distribution: str,
                            has_cs: bool, chunk: int):
     """Per-lane key chain + outside draws for ``chunk`` events.
 
-    A tiny-carry scan replays :func:`_lane_randomness`'s per-event split
-    arity and draw order ``chunk`` times (same subkeys, same scalar-shape
-    primitives — the megastep stream is bitwise the single-step stream);
-    returns ``(chain [K, chunk, 2], c_new [K, chunk], fscal [K, chunk,
-    4])`` with ``chain[:, i]`` the carried key after ``i + 1`` events.
+    A scan over the chunk replays :func:`_lane_randomness`'s per-event
+    6-way split (integer-exact), collecting every event's subkeys; the
+    draws then run elementwise, vmapped over ``[K, chunk]``, on those
+    subkeys.  Keeping the float draws out of the scan body is what makes
+    the megastep stream bitwise the single-step one: a scalar draw inside
+    a length-1 scan (which XLA inlines) and inside a length-E scan body
+    are different fusion contexts, and the lognormal's erf_inv/exp chain
+    can contract differently (by 1 ulp) between them.  Returns ``(chain
+    [K, chunk, 2], c_new [K, chunk], fscal [K, chunk, 4])`` with
+    ``chain[:, i]`` the carried key after ``i + 1`` events.
     """
     law = get_law(distribution)
     dtype = state.finish.dtype
     K, n = params.p.shape
     n_acts = (params.n_active if params.n_active is not None
               else jnp.full((K,), n))
-
     mu_cs = params.mu_cs if has_cs else jnp.zeros_like(params.p[..., 0])
 
-    def draw_one(k, p_row, mu_d_row, mu_cs_i, n_act):
-        k2, k_up, k_disp_cli, k_disp_svc, k_comp, k_cs = (
-            jax.random.split(k, 6))
+    def split6(keys, _):
+        ks = jax.vmap(lambda k: jax.random.split(k, 6))(keys)
+        return ks[:, 0], ks
+
+    _, subkeys = jax.lax.scan(split6, state.key, None, length=chunk)
+    subkeys = jnp.moveaxis(subkeys, 0, 1)  # [K, chunk, 6, 2]
+
+    def draw_one(ks, p_row, mu_d_row, mu_cs_i, n_act):
+        _, k_up, k_disp_cli, k_disp_svc, k_comp, k_cs = (
+            ks[i] for i in range(6))
         c_new = E._route_client(p_row, k_disp_cli, n_act)
         one_rate = jnp.ones((), dtype)
         e_up = law.device_draw(k_up, one_rate)
@@ -547,32 +585,13 @@ def _lane_chunk_randomness(params: NetworkParams, state, distribution: str,
         svc_down = law.device_draw(k_disp_svc, mu_d_row[c_new])
         svc_cs = (law.device_draw(k_cs, mu_cs_i) if has_cs
                   else jnp.zeros((), dtype))
-        fscal = jnp.stack([e_up, e_comp, svc_down, svc_cs]).astype(dtype)
-        return k2, c_new, fscal
+        return c_new, jnp.stack([e_up, e_comp, svc_down, svc_cs]).astype(
+            dtype)
 
-    def body(keys, _):
-        # hermetic draw region: optimization_barrier pins the fusion
-        # boundaries around each event's draws, so XLA's mul-add (FMA)
-        # contraction inside them cannot depend on the surrounding
-        # program.  Without it a trip-count-1 scan (the chunk=1 path) is
-        # inlined by the while-loop simplifier and the lognormal's
-        # exp(normal - log(rate) - 0.5) chain contracts differently than
-        # in the length-E scan body — a 1-ulp finish-clock split between
-        # megastep and single-step.  (The scan runs over the CHUNK axis
-        # with lanes vmapped inside, because optimization_barrier has no
-        # batching rule — the lowered per-step ops are the same either
-        # way.)
-        keys, p_b, mu_d_b, mu_cs_b, n_b = jax.lax.optimization_barrier(
-            (keys, params.p, params.mu_d, mu_cs, n_acts))
-        k2, c_new, fscal = jax.vmap(draw_one)(keys, p_b, mu_d_b, mu_cs_b,
-                                              n_b)
-        k2, c_new, fscal = jax.lax.optimization_barrier((k2, c_new, fscal))
-        return k2, (k2, c_new, fscal)
-
-    _, (chain, c_new, fscal) = jax.lax.scan(body, state.key, None,
-                                            length=chunk)
-    return (jnp.moveaxis(chain, 0, 1), jnp.moveaxis(c_new, 0, 1),
-            jnp.moveaxis(fscal, 0, 1))
+    per_event = jax.vmap(draw_one, in_axes=(0, None, None, None, None))
+    c_new, fscal = jax.vmap(per_event)(subkeys, params.p, params.mu_d, mu_cs,
+                                       n_acts)
+    return subkeys[:, :, 0], c_new, fscal
 
 
 def megastep_event_pallas(params: NetworkParams, state, *, chunk: int,

@@ -1,8 +1,8 @@
 """Jitted public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True because this container is CPU-only (TPU is
-the deployment target); on TPU pass ``interpret=False`` (the launcher does
-this when ``jax.default_backend() == "tpu"``).
+``interpret=None`` (the default) defers to
+:func:`repro.kernels.default_interpret`: compiled on a TPU, interpreted on
+any other backend.
 """
 from __future__ import annotations
 
@@ -11,7 +11,8 @@ from typing import Optional
 
 import jax
 
-from .buzen import buzen_pallas, default_interpret
+from . import default_interpret
+from .buzen import buzen_pallas
 from .decode_attention import decode_attention_pallas
 from .flash_attention import flash_attention_pallas
 from .fused_update import fused_async_update as _fused_update

@@ -4,8 +4,7 @@ A FUNCTION (not a module-level constant) so importing this module never
 touches jax device state; the dry-run sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before first jax
 init to obtain the placeholder devices.  Mesh construction goes through
-``repro.compat.make_mesh`` so the ``axis_types`` kwarg degrades gracefully
-on older jax.
+``repro.compat.make_mesh`` (every axis ``AxisType.Auto``).
 """
 from __future__ import annotations
 

@@ -675,7 +675,7 @@ class DataSpec:
     Registered datasets (``repro.data.DATASETS``): ``"synthetic"`` (the
     procedural class-glyph images) and ``"emnist"`` — a download-free
     EMNIST-style loader that reads a local ``.npz`` cache
-    (``$REPRO_EMNIST_PATH`` / ``~/.cache/repro/emnist.npz``) when present
+    (``$REPRO_EMNIST_PATH`` / ``<checkout>/data/emnist.npz``) when present
     and otherwise falls back to a deterministic synthetic stand-in with
     the same 28x28 tensor format (``repro.data.emnist``)."""
 

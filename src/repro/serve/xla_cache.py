@@ -1,9 +1,13 @@
 """Warm restarts: the jax persistent compilation cache.
 
 ``enable_persistent_cache()`` points jax at an on-disk XLA executable
-cache (``JAX_COMPILATION_CACHE_DIR``, default ``~/.cache/repro/xla``)
-with the thresholds opened up so every resident program qualifies
+cache with the thresholds opened up so every resident program qualifies
 (CPU compiles are fast and small — the defaults would skip them all).
+The directory is ``$JAX_COMPILATION_CACHE_DIR`` when that is set, and
+otherwise one fixed directory inside the checkout, ``<repo>/.jax_cache``
+(ignored by git): the path is part of what a later run must find again,
+so it is never made from a temporary name, a process id or the time.
+
 A restarted server's first request then deserializes its programs
 instead of recompiling: ``repro.analysis.tracecheck`` counts the
 persistent-cache hits separately (``Watch.fresh_compiles``), and the
@@ -15,21 +19,22 @@ Call it before the first jit dispatch; it is idempotent.
 from __future__ import annotations
 
 import os
-from typing import Optional
 
-DEFAULT_CACHE_DIR = "~/.cache/repro/xla"
+from .. import CHECKOUT_ROOT
+
+DEFAULT_CACHE_DIR = os.path.join(CHECKOUT_ROOT, ".jax_cache")
 
 
 def default_cache_dir() -> str:
-    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    return env if env else os.path.expanduser(DEFAULT_CACHE_DIR)
+    """``$JAX_COMPILATION_CACHE_DIR`` if set, else :data:`DEFAULT_CACHE_DIR`."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
 
 
-def enable_persistent_cache(cache_dir: Optional[str] = None) -> str:
+def enable_persistent_cache() -> str:
     """Enable the on-disk compilation cache; returns the directory."""
     import jax
 
-    path = cache_dir if cache_dir else default_cache_dir()
+    path = default_cache_dir()
     os.makedirs(path, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", path)
     # cache EVERY executable: CPU compiles are below the default 1 MiB /

@@ -122,6 +122,7 @@ def _build_sharded_fn(nu: int, wu: int, distribution: str, m_max: int,
             out = jfn(prm, mv, ks)
         return jax.tree_util.tree_map(lambda x: x[:L], out)
 
+    wrapper.mesh = mesh  # the lane mesh, for callers that check its extent
     return wrapper
 
 

@@ -67,6 +67,33 @@ def test_class_log_Z_matches_expanded(with_cs):
                                rtol=1e-12)
 
 
+@pytest.mark.parametrize("count", [0, 1, 3, 700_000])
+def test_negbinom_log_weights_match_lgamma(count):
+    """The class series' ``log C(j + count - 1, j)`` running sum equals the
+    lgamma form; ``count = 1`` is exactly the geometric series and
+    ``count = 0`` the convolution identity."""
+    from jax.scipy.special import gammaln
+
+    from repro.core.buzen import _log_multisets
+
+    m_max = 64
+    got = np.asarray(_log_multisets(count, m_max))
+    assert got[0] == 0.0
+    if count == 0:
+        assert np.all(np.isneginf(got[1:]))
+        return
+    j = np.arange(m_max + 1, dtype=np.float64)
+    want = np.asarray(gammaln(j + count) - gammaln(j + 1.0)
+                      - gammaln(float(count)))
+    # the lgamma form cancels terms as large as lgamma(count + m_max): its
+    # own error is a few ulps of that
+    scale = float(gammaln(float(count + m_max)))
+    np.testing.assert_allclose(got, want, rtol=1e-12,
+                               atol=16 * np.finfo(np.float64).eps * scale)
+    if count == 1:
+        assert np.all(got == 0.0)
+
+
 @pytest.mark.parametrize("with_cs", [False, True])
 def test_class_closed_forms_match_expanded(with_cs):
     cls = example_classes(with_cs)

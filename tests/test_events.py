@@ -227,8 +227,10 @@ def test_device_trainer_matches_host_statistics():
 
     clients, test, net = _tiny_fl_problem()
     m = 4
-    horizon = 120.0
-    kw = dict(eta=0.05, batch_size=16, eval_every_time=30.0, seed=0)
+    # ~700 updates per run: one run's update count then fluctuates by
+    # ~8% around Prop. 4's rate, well inside the 0.35 device-vs-host band
+    horizon = 480.0
+    kw = dict(eta=0.05, batch_size=16, eval_every_time=120.0, seed=0)
     model = mlp_classifier(28 * 28, 4, hidden=(16,))
     dev = AsyncFLTrainer(model, clients, net, m,
                          config=AsyncFLConfig(backend="device", **kw),
@@ -239,7 +241,7 @@ def test_device_trainer_matches_host_statistics():
                           test_data=test)
     hlog = host.run(horizon_time=horizon)
 
-    # same eval grid shape: 0, 30, ..., 90 < t_end plus the final point
+    # same eval grid shape: 0, 120, ..., 360 < t_end plus the final point
     assert dlog.times == hlog.times
     assert dlog.updates[-1] == pytest.approx(hlog.updates[-1], rel=0.35)
     assert np.isfinite(dlog.losses).all()

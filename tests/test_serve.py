@@ -420,3 +420,20 @@ def test_restarted_server_first_request_pays_zero_fresh_compiles(tmp_path):
     warm = boot()
     assert warm["compiles"] > 0
     assert warm["fresh"] == 0, warm  # restart: everything from disk
+
+
+@pytest.mark.parametrize("from_env", (True, False))
+def test_compile_cache_dir_is_env_or_fixed_in_checkout(monkeypatch, tmp_path,
+                                                       from_env):
+    """``$JAX_COMPILATION_CACHE_DIR`` wins when set; otherwise the cache is
+    the one fixed ``<repo>/.jax_cache``, the same path in every process."""
+    from repro.serve import xla_cache
+
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
+        assert xla_cache.default_cache_dir() == str(tmp_path / "xla")
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert xla_cache.default_cache_dir() == os.path.join(root,
+                                                             ".jax_cache")
