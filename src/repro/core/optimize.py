@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.metrics import span
 from . import numerics  # noqa: F401
 from .buzen import ClassParams, NetworkParams, log_normalizing_constants
 from .complexity import LearningConstants, round_complexity, wallclock_time
@@ -118,6 +119,22 @@ def optimize_routing(
     return OptResult(p=p, m=m, value=float(objective(p, m)), history=list(map(float, vals)))
 
 
+def _run_staged(fn, *args):
+    """``jax.jit(fn)(*args)`` through jit's staged API, one span of the
+    current ``repro.obs.metrics`` registry per stage: ``optimize.lower``
+    (trace and lower), ``optimize.compile`` (XLA compile or persistent-cache
+    load) and ``optimize.run`` (the call, to ``block_until_ready``)."""
+    with span("optimize.lower"):
+        lowered = jax.jit(fn).lower(*args)
+    with span("optimize.compile"):
+        compiled = lowered.compile()
+    with span("optimize.run"):
+        out = compiled(*args)
+        # dropped while the device runs, as a plain jit call drops them
+        del lowered, compiled
+        return jax.block_until_ready(out)
+
+
 def _sharded_rows(solve, theta0, m_grid, ctx, B: int):
     """Run a row-local solver with its row axis split over local devices.
 
@@ -142,15 +159,10 @@ def _sharded_rows(solve, theta0, m_grid, ctx, B: int):
 
     mesh = make_mesh((ndev,), ("lanes",))
     spec = PartitionSpec("lanes")
-    if ctx is None:
-        fn = shard_map(lambda th, mm: solve(th, mm, None), mesh,
-                       in_specs=(spec, spec), out_specs=(spec, spec))
-        ps, vals = jax.jit(fn)(pad_rows(theta0), pad_rows(m_grid))
-    else:
-        fn = shard_map(solve, mesh, in_specs=(spec, spec, spec),
-                       out_specs=(spec, spec))
-        ps, vals = jax.jit(fn)(pad_rows(theta0), pad_rows(m_grid),
-                               pad_rows(jnp.asarray(ctx)))
+    fn = shard_map(solve, mesh, in_specs=(spec, spec, spec),
+                   out_specs=(spec, spec))
+    ps, vals = _run_staged(fn, pad_rows(theta0), pad_rows(m_grid),
+                           pad_rows(ctx))
     return ps[:B], vals[:B]
 
 
@@ -256,23 +268,22 @@ def batched_concurrency_sweep(
             vals = jax.vmap(objective)(ps, m_rows, logZ, ctx_rows)
         return ps, vals
 
-    def solve(theta0_rows, m_rows, ctx_rows):
+    def concurrency_sweep(theta0_rows, m_rows, ctx_rows):
         def loss(thetas):
             return jnp.sum(row_values(thetas, m_rows, ctx_rows)[1])
 
         theta, _ = _adam_minimize(loss, theta0_rows, steps, lr)
         return row_values(theta, m_rows, ctx_rows)
 
-    # both paths jit the SAME solve (scan + final evaluation as one
-    # program): jit(solve) == jit(shard_map(solve)) bitwise, whereas an
-    # eager final evaluation fuses differently in the last bit
+    # both paths jit the SAME function (scan + final evaluation as one
+    # program, named concurrency_sweep in the profiler trace):
+    # jit(f) == jit(shard_map(f)) bitwise, whereas an eager final
+    # evaluation fuses differently in the last bit
+    ctx = None if ctx is None else jnp.asarray(ctx)
     if shard:
-        ps, vals = _sharded_rows(solve, theta0, m_grid, ctx, B)
-    elif ctx is None:
-        ps, vals = jax.jit(lambda th, mm: solve(th, mm, None))(theta0,
-                                                               m_grid)
+        ps, vals = _sharded_rows(concurrency_sweep, theta0, m_grid, ctx, B)
     else:
-        ps, vals = jax.jit(solve)(theta0, m_grid, jnp.asarray(ctx))
+        ps, vals = _run_staged(concurrency_sweep, theta0, m_grid, ctx)
 
     m_np = np.asarray(m_grid)
     vals_np = np.asarray(vals)

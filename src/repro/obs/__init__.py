@@ -6,7 +6,8 @@ Layers (see ``README.md`` "Observability"):
     event scan and the fused trainer (bitwise non-invasive; statically
     disabled at capacity 0);
   * ``repro.obs.metrics`` — the process-wide counters/histograms/spans
-    registry (``repro.serve.metrics`` is a backward-compat shim);
+    registry, shared by the suite planner, the optimizer and
+    ``repro.serve``; every span is also a profiler annotation;
   * ``repro.obs.trace`` — Chrome-trace/Perfetto JSON export of the
     simulated closed-network timeline plus host spans and compiles;
   * ``repro.obs.drift`` — empirical-vs-closed-form drift monitors with

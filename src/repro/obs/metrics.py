@@ -8,21 +8,29 @@ server's admission/dispatch path, so both report the same per-bucket
 counters: programs compiled, lanes dispatched, cache hits, and wall-clock
 latency percentiles.
 
-This module moved here from ``repro.serve.metrics`` (which remains as a
-backward-compat shim) when observability grew beyond the server: the same
-registry now also records a bounded window of **host spans** (every
+The same registry records a bounded window of **host spans** (every
 ``timed()`` block keeps its start/duration for the Perfetto exporter in
 ``repro.obs.trace``) and renders a Prometheus-style text
 :meth:`Metrics.exposition` served by the ``metrics`` verb of
-``repro.serve``.
+``repro.serve``.  Every span is also a ``jax.profiler.TraceAnnotation`` of
+the same name (its labels as the annotation's arguments), so under a
+profiler session it lands on the host plane of the trace, on the
+profiler's clock, beside the device programs it launched.
+
+The registry of the innermost open ``timed()`` block is the *current*
+one (a ``contextvars.ContextVar``, so per thread): the module-level
+:func:`span` records into it, which lets code below the planner (the
+optimizer's lower/compile/run stages) record spans without a registry
+argument.  With no block open, :func:`span` only opens the annotation.
 
 The registry is thread-safe (the server observes from reader threads and
-the dispatcher thread concurrently) and dependency-free: histograms keep
-a bounded reservoir of recent observations — exact percentiles over the
-window, O(1) memory.
+the dispatcher thread concurrently) and imports jax only once a span
+opens; histograms keep a bounded reservoir of recent observations —
+exact percentiles over the window, O(1) memory.
 """
 from __future__ import annotations
 
+import contextvars
 import re
 import threading
 import time
@@ -33,6 +41,10 @@ _RESERVOIR = 2048  # recent-observation window per histogram
 _SPANS = 4096      # recent-span window kept for the trace exporter
 
 _NAME_RE = re.compile(r"[^a-zA-Z0-9_:]")
+
+# the registry of the innermost open ``timed()`` block (None: no block open)
+_CURRENT: contextvars.ContextVar = contextvars.ContextVar(
+    "repro_obs_metrics", default=None)
 
 
 class Histogram:
@@ -108,8 +120,9 @@ class Metrics:
 
     def timed(self, name: str, **labels) -> "_Timer":
         """``with metrics.timed("suite.dispatch", mode="train"): ...``
-        observes the block's wall-clock seconds (and keeps the span for
-        the trace exporter)."""
+        observes the block's wall-clock seconds, keeps the span for the
+        trace exporter, annotates the profiler trace, and makes this
+        registry the current one inside the block (see :func:`span`)."""
         return _Timer(self, name, labels)
 
     def record_span(self, name: str, labels: dict, start: float,
@@ -199,20 +212,37 @@ def _render_labels(labels: dict, **extra) -> str:
     return f"{{{inner}}}"
 
 
-class _Timer:
-    __slots__ = ("_metrics", "_name", "_labels", "_t0")
+def span(name: str, **labels) -> "_Timer":
+    """``with span("optimize.compile"): ...`` — a span of the current
+    registry (the innermost open ``timed()`` block's), recorded as
+    :meth:`Metrics.timed` records it.  With no block open it records
+    nothing and only annotates the profiler trace."""
+    return _Timer(_CURRENT.get(), name, labels)
 
-    def __init__(self, metrics: Metrics, name: str, labels: dict):
+
+class _Timer:
+    __slots__ = ("_metrics", "_name", "_labels", "_t0", "_ann", "_token")
+
+    def __init__(self, metrics: Optional[Metrics], name: str, labels: dict):
         self._metrics = metrics
         self._name = name
         self._labels = labels
 
     def __enter__(self) -> "_Timer":
+        from jax.profiler import TraceAnnotation  # jax only once one opens
+
+        self._ann = TraceAnnotation(self._name, **self._labels)
+        self._ann.__enter__()
+        if self._metrics is not None:
+            self._token = _CURRENT.set(self._metrics)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> Optional[bool]:
         dt = time.perf_counter() - self._t0
-        self._metrics.observe(self._name, dt, **self._labels)
-        self._metrics.record_span(self._name, self._labels, self._t0, dt)
+        self._ann.__exit__(*exc)
+        if self._metrics is not None:
+            _CURRENT.reset(self._token)
+            self._metrics.observe(self._name, dt, **self._labels)
+            self._metrics.record_span(self._name, self._labels, self._t0, dt)
         return None

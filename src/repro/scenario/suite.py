@@ -429,26 +429,34 @@ class ScenarioSuite:
         (``m_max``/``steps``/``search``) — so a suite sweeping power
         profiles or optimizer budgets never reuses a stale tau*/E*.
         """
-        caches: dict = {}
-        for name, scn in self.scenarios.items():
-            if name in self._strategies:
-                continue
-            net_key = (str(scn.network.to_dict()),
-                       str(scn.learning.to_dict()),
-                       str(None if scn.energy is None
-                           else scn.energy.to_dict()),
-                       scn.strategy.m_max, scn.strategy.steps,
-                       scn.strategy.search)
-            shared = caches.setdefault(net_key, {"cache": {}, "resolved": {}})
-            pm = resolve_strategy(scn, resolved=shared["resolved"],
-                                  cache=shared["cache"])
-            shared["resolved"][scn.strategy.name] = pm
-            self._strategies[name] = pm
-        return {name: self._strategies[name] for name in self.scenarios}
+        with self.metrics.timed("suite.resolve"):
+            caches: dict = {}
+            for name, scn in self.scenarios.items():
+                if name in self._strategies:
+                    continue
+                net_key = (str(scn.network.to_dict()),
+                           str(scn.learning.to_dict()),
+                           str(None if scn.energy is None
+                               else scn.energy.to_dict()),
+                           scn.strategy.m_max, scn.strategy.steps,
+                           scn.strategy.search)
+                shared = caches.setdefault(net_key,
+                                           {"cache": {}, "resolved": {}})
+                pm = resolve_strategy(scn, resolved=shared["resolved"],
+                                      cache=shared["cache"])
+                shared["resolved"][scn.strategy.name] = pm
+                self._strategies[name] = pm
+            return {name: self._strategies[name] for name in self.scenarios}
 
     # -- dispatch ------------------------------------------------------------
 
     def run(self, mode: str = "analyze", **kw) -> SuiteResult:
+        """Every scenario in ``mode``.  Host spans (``repro.obs.metrics``):
+        ``suite.run`` holds ``suite.resolve`` and then, per bucket,
+        ``suite.pack`` (lane padding and stacking, the program lookup),
+        ``suite.dispatch`` (the program, to ``block_until_ready``) and
+        ``suite.unpack`` (per-lane slicing and unpadding, the result
+        cache); train buckets record ``suite.dispatch`` only."""
         runners = {"analyze": self._run_analyze,
                    "simulate": self._run_simulate,
                    "train": self._run_train}
@@ -504,65 +512,69 @@ class ScenarioSuite:
         programs = 0
         for (has_cs, power_sig, is_classes), members in buckets.items():
             has_power = power_sig is not None
-            m_max = max(strategies[name][1] for name in members)
-            axis_max = c_max if is_classes else n_max
-            if is_classes:
-                prm = _stack_params(
-                    [pad_classes(
-                        self.scenarios[n_].class_params(strategies[n_][0]),
-                        c_max) for n_ in members])
-            else:
-                prm = _stack_params(
-                    [pad_network(
-                        self.scenarios[n_].params(strategies[n_][0]),
-                        n_max) for n_ in members])
-            consts = _stack_consts([self.scenarios[n_].consts
-                                    for n_ in members])
-            power = (_stack_power([_pad_power(self.scenarios[n_].power(),
-                                              axis_max) for n_ in members])
-                     if has_power else None)
-            m_vec = jnp.asarray([strategies[n_][1] for n_ in members],
-                                jnp.int64)
-            rho = jnp.asarray([self.scenarios[n_].objective.rho
-                               for n_ in members])
-            sig = ("analyze", is_classes, axis_max, has_cs, power_sig, m_max)
-            fn = self._jit_cache.get(sig)
-            if fn is None:
-                build = (_build_analyze_classes if is_classes
-                         else _build_analyze)
-                fn = self._jit_cache[sig] = build(m_max, has_power)
-                programs += 1
+            with self.metrics.timed("suite.pack", mode="analyze"):
+                m_max = max(strategies[name][1] for name in members)
+                axis_max = c_max if is_classes else n_max
+                if is_classes:
+                    prm = _stack_params(
+                        [pad_classes(
+                            self.scenarios[n_].class_params(strategies[n_][0]),
+                            c_max) for n_ in members])
+                else:
+                    prm = _stack_params(
+                        [pad_network(
+                            self.scenarios[n_].params(strategies[n_][0]),
+                            n_max) for n_ in members])
+                consts = _stack_consts([self.scenarios[n_].consts
+                                        for n_ in members])
+                power = (_stack_power([_pad_power(self.scenarios[n_].power(),
+                                                  axis_max) for n_ in members])
+                         if has_power else None)
+                m_vec = jnp.asarray([strategies[n_][1] for n_ in members],
+                                    jnp.int64)
+                rho = jnp.asarray([self.scenarios[n_].objective.rho
+                                   for n_ in members])
+                sig = ("analyze", is_classes, axis_max, has_cs, power_sig,
+                       m_max)
+                fn = self._jit_cache.get(sig)
+                if fn is None:
+                    build = (_build_analyze_classes if is_classes
+                             else _build_analyze)
+                    fn = self._jit_cache[sig] = build(m_max, has_power)
+                    programs += 1
             with self.metrics.timed("suite.dispatch", mode="analyze"):
                 out = jax.block_until_ready(fn(prm, m_vec, consts, power,
                                                rho))
             self.metrics.observe("suite.lanes_per_dispatch", len(members),
                                  mode="analyze")
-            for i, name in enumerate(members):
-                # class rows report per-CLASS delays (one member each);
-                # truncate to the scenario's own axis either way
-                n_i = (self.scenarios[name].network.classes.C if is_classes
-                       else self.scenarios[name].n)
-                row = {k: np.asarray(v[i]) for k, v in out.items()}
-                row["delays"] = row["delays"][:n_i]
-                p, m = strategies[name]
-                obj_name = self.scenarios[name].objective.name
-                # None (not a mislabeled tau) for objectives analyze cannot
-                # evaluate: registered extensions without an analyze column
-                val_key = _ANALYZE_KEY.get(obj_name)
-                entries[name] = {
-                    "p": p, "m": m, "eta": self.scenarios[name].eta(),
-                    "throughput": float(row["throughput"]),
-                    "K_eps": float(row["K_eps"]),
-                    "tau": float(row["tau"]),
-                    "delays": row["delays"],  # E0[D_i] (Thm 2)
-                    "energy": (float(row["energy"]) if has_power else None),
-                    "objective": obj_name,
-                    "value": (float(row[val_key])
-                              if val_key is not None and val_key in row
-                              else None),
-                }
-                self._result_cache[
-                    ("analyze", self.scenarios[name].hash())] = entries[name]
+            with self.metrics.timed("suite.unpack", mode="analyze"):
+                for i, name in enumerate(members):
+                    # class rows report per-CLASS delays (one member each);
+                    # truncate to the scenario's own axis either way
+                    n_i = (self.scenarios[name].network.classes.C if is_classes
+                           else self.scenarios[name].n)
+                    row = {k: np.asarray(v[i]) for k, v in out.items()}
+                    row["delays"] = row["delays"][:n_i]
+                    p, m = strategies[name]
+                    obj_name = self.scenarios[name].objective.name
+                    # None (not a mislabeled tau) for objectives analyze cannot
+                    # evaluate: registered extensions without an analyze column
+                    val_key = _ANALYZE_KEY.get(obj_name)
+                    entries[name] = {
+                        "p": p, "m": m, "eta": self.scenarios[name].eta(),
+                        "throughput": float(row["throughput"]),
+                        "K_eps": float(row["K_eps"]),
+                        "tau": float(row["tau"]),
+                        "delays": row["delays"],  # E0[D_i] (Thm 2)
+                        "energy": (float(row["energy"]) if has_power
+                                   else None),
+                        "objective": obj_name,
+                        "value": (float(row[val_key])
+                                  if val_key is not None and val_key in row
+                                  else None),
+                    }
+                    ckey = ("analyze", self.scenarios[name].hash())
+                    self._result_cache[ckey] = entries[name]
         return SuiteResult(mode="analyze", entries=entries, seeds=self.seeds,
                            lanes=len(names), programs=programs,
                            strategies=strategies, cache_hits=cache_hits)
@@ -646,97 +658,102 @@ class ScenarioSuite:
                     todo.append((name, ckey))
             if not todo:
                 continue
-            axis_max = c_max if is_classes else n_max
-            if is_classes:
-                lane_params = _stack_params(
-                    [pad_classes(
-                        self.scenarios[n_].class_params(strategies[n_][0]),
-                        c_max)
-                     for n_, _ in todo for _ in self.seeds])
-            else:
-                lane_params = _stack_params(
-                    [pad_network(
-                        self.scenarios[n_].params(strategies[n_][0]),
-                        n_max)
-                     for n_, _ in todo for _ in self.seeds])
-            power = (_stack_power([_pad_power(self.scenarios[n_].power(),
-                                              axis_max)
-                                   for n_, _ in todo for _ in self.seeds])
-                     if has_power else None)
-            m_vec = jnp.asarray([strategies[n_][1]
-                                 for n_, _ in todo for _ in self.seeds],
-                                jnp.int32)
-            keys = jnp.stack([jax.random.PRNGKey(s)
-                              for _ in todo for s in self.seeds])
-            sig = ("simulate", is_classes, axis_max, law, has_cs, power_sig,
-                   mx, int(num_updates), int(warmup), bk, interp, tr, ck)
-            fn = self._jit_cache.get(sig)
-            if fn is None:
+            with self.metrics.timed("suite.pack", mode="simulate"):
+                axis_max = c_max if is_classes else n_max
                 if is_classes:
-                    fn = self._jit_cache[sig] = build_class_lanes_fn(
-                        bk, int(num_updates), int(warmup), law, mx,
-                        has_power, trace_events=tr, chunk=ck)
+                    lane_params = _stack_params(
+                        [pad_classes(
+                            self.scenarios[n_].class_params(strategies[n_][0]),
+                            c_max)
+                         for n_, _ in todo for _ in self.seeds])
                 else:
-                    fn = self._jit_cache[sig] = build_lanes_fn(
-                        bk, int(num_updates), int(warmup), law, mx,
-                        has_power, interpret=interp, trace_events=tr,
-                        chunk=ck)
-                programs += 1
+                    lane_params = _stack_params(
+                        [pad_network(
+                            self.scenarios[n_].params(strategies[n_][0]),
+                            n_max)
+                         for n_, _ in todo for _ in self.seeds])
+                power = (_stack_power([_pad_power(self.scenarios[n_].power(),
+                                                  axis_max)
+                                       for n_, _ in todo for _ in self.seeds])
+                         if has_power else None)
+                m_vec = jnp.asarray([strategies[n_][1]
+                                     for n_, _ in todo for _ in self.seeds],
+                                    jnp.int32)
+                keys = jnp.stack([jax.random.PRNGKey(s)
+                                  for _ in todo for s in self.seeds])
+                sig = ("simulate", is_classes, axis_max, law, has_cs,
+                       power_sig, mx, int(num_updates), int(warmup), bk,
+                       interp, tr, ck)
+                fn = self._jit_cache.get(sig)
+                if fn is None:
+                    if is_classes:
+                        fn = self._jit_cache[sig] = build_class_lanes_fn(
+                            bk, int(num_updates), int(warmup), law, mx,
+                            has_power, trace_events=tr, chunk=ck)
+                    else:
+                        fn = self._jit_cache[sig] = build_lanes_fn(
+                            bk, int(num_updates), int(warmup), law, mx,
+                            has_power, interpret=interp, trace_events=tr,
+                            chunk=ck)
+                    programs += 1
             with self.metrics.timed("suite.dispatch", mode="simulate"):
                 out = jax.block_until_ready(
                     fn(lane_params, m_vec, keys, power))
             stats, rings = out if tr else (out, None)
             self.metrics.observe("suite.lanes_per_dispatch", len(todo) * S,
                                  mode="simulate")
-            for i, (name, ckey) in enumerate(todo):
-                # class lanes: statistics are per-CLASS — unpad on the
-                # class axis (expand_class_stats recovers per-member views)
-                n_i = (self.scenarios[name].network.classes.C if is_classes
-                       else self.scenarios[name].n)
-                entries[name] = [
-                    unpad_stats(jax.tree_util.tree_map(
-                        lambda a: a[i * S + j], stats), n_i)
-                    for j in range(S)]
-                self._result_cache[ckey] = entries[name]
-                if tr:
-                    from ..obs.drift import drift_report, predict
-                    from ..obs.rings import decode
-
-                    scn = self.scenarios[name]
-                    m_i = strategies[name][1]
-                    # closed forms are seed- and run-invariant: one predict
-                    # per (scenario, m), cached across suite runs
-                    pkey = ("drift_pred", scn.hash(), int(m_i))
-                    preds = self._result_cache.get(pkey)
-                    if preds is None:
-                        # Scenario.params() expands a class network, so the
-                        # closed forms always see the member population
-                        preds = predict(scn.params(strategies[name][0]), m_i)
-                        if is_classes:
-                            # class rings index stations per CLASS: fold the
-                            # per-member delay predictions onto the class
-                            # axis (E0[D_c] = sum of the members' shares)
-                            cnt = np.asarray(
-                                scn.class_params(strategies[name][0]).count)
-                            lbl = np.repeat(np.arange(len(cnt)), cnt)
-                            d = np.bincount(
-                                lbl,
-                                weights=np.asarray(preds["delays"],
-                                                   dtype=np.float64),
-                                minlength=len(cnt))
-                            preds = dict(preds,
-                                         delays=[float(v) for v in d])
-                        self._result_cache[pkey] = preds
-                    traces[name] = [
-                        decode(jax.tree_util.tree_map(
-                            lambda a: a[i * S + j], rings))
+            with self.metrics.timed("suite.unpack", mode="simulate"):
+                for i, (name, ckey) in enumerate(todo):
+                    # class lanes: statistics are per-CLASS — unpad on the
+                    # class axis (expand_class_stats recovers per-member views)
+                    n_i = (self.scenarios[name].network.classes.C if is_classes
+                           else self.scenarios[name].n)
+                    entries[name] = [
+                        unpad_stats(jax.tree_util.tree_map(
+                            lambda a: a[i * S + j], stats), n_i)
                         for j in range(S)]
-                    drift[name] = [
-                        drift_report(d, predictions=preds, law=law,
-                                     tolerance=scn.trace.tolerance)
-                        for d in traces[name]]
-                    self._result_cache[("trace",) + ckey] = (traces[name],
-                                                             drift[name])
+                    self._result_cache[ckey] = entries[name]
+            if not tr:
+                continue
+            from ..obs.drift import drift_report, predict
+            from ..obs.rings import decode
+
+            for i, (name, ckey) in enumerate(todo):
+                scn = self.scenarios[name]
+                m_i = strategies[name][1]
+                # closed forms are seed- and run-invariant: one predict
+                # per (scenario, m), cached across suite runs
+                pkey = ("drift_pred", scn.hash(), int(m_i))
+                preds = self._result_cache.get(pkey)
+                if preds is None:
+                    # Scenario.params() expands a class network, so the
+                    # closed forms always see the member population
+                    preds = predict(scn.params(strategies[name][0]), m_i)
+                    if is_classes:
+                        # class rings index stations per CLASS: fold the
+                        # per-member delay predictions onto the class
+                        # axis (E0[D_c] = sum of the members' shares)
+                        cnt = np.asarray(
+                            scn.class_params(strategies[name][0]).count)
+                        lbl = np.repeat(np.arange(len(cnt)), cnt)
+                        d = np.bincount(
+                            lbl,
+                            weights=np.asarray(preds["delays"],
+                                               dtype=np.float64),
+                            minlength=len(cnt))
+                        preds = dict(preds,
+                                     delays=[float(v) for v in d])
+                    self._result_cache[pkey] = preds
+                traces[name] = [
+                    decode(jax.tree_util.tree_map(
+                        lambda a: a[i * S + j], rings))
+                    for j in range(S)]
+                drift[name] = [
+                    drift_report(d, predictions=preds, law=law,
+                                 tolerance=scn.trace.tolerance)
+                    for d in traces[name]]
+                self._result_cache[("trace",) + ckey] = (traces[name],
+                                                         drift[name])
         return SuiteResult(mode="simulate", entries=entries, seeds=self.seeds,
                            lanes=len(names) * S, programs=programs,
                            strategies=strategies, cache_hits=cache_hits,

@@ -26,9 +26,9 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+from ..obs.metrics import Metrics
 from ..scenario import ScenarioSuite
 from ..scenario.suite import SuiteCaches, resolve_strategy
-from .metrics import Metrics
 from .protocol import MAX_M, Request, WireError, encode_entry
 
 

@@ -31,9 +31,9 @@ import threading
 import time
 from typing import Optional
 
+from ..obs.metrics import Metrics
 from .batcher import MicroBatcher
 from .executor import Executor
-from .metrics import Metrics
 from .protocol import (Request, WireError, decode_line, encode,
                        parse_request)
 

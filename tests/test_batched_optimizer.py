@@ -228,3 +228,43 @@ def test_pallas_backend_gradient_matches_reference():
     g_pal = jax.grad(lambda p: val(p, "pallas"))(params.p)
     np.testing.assert_allclose(np.asarray(g_pal), np.asarray(g_ref),
                                rtol=2e-3, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the staged jit (lower / compile / run spans) == a plain jit call
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("with_ctx", [False, True])
+def test_staged_sweep_bitwise_equals_plain_jit(monkeypatch, with_ctx):
+    from repro.core import optimize
+
+    rng = np.random.default_rng(17)
+    n, m_max = 3, 5
+    params = reference_params(rng, n)
+    calls = []
+    staged = optimize._run_staged
+
+    def spy(fn, *args):
+        calls.append((fn, args))
+        return staged(fn, *args)
+
+    monkeypatch.setattr(optimize, "_run_staged", spy)
+    if with_ctx:
+        power = PowerProfile.from_dvfs(
+            jnp.asarray(rng.uniform(0.1, 2.0, n)), params.mu_c,
+            jnp.asarray(rng.uniform(1.0, 5.0, n)),
+            jnp.asarray(rng.uniform(1.0, 5.0, n)))
+        joint_optimal(params, CONSTS, power, 0.3, 10.0, 100.0, m_max=m_max,
+                      steps=20)
+    else:
+        batched_concurrency_sweep(
+            make_time_objective_padded(params, CONSTS, m_max), params,
+            m_grid=jnp.arange(2, m_max + 1), steps=20)
+    (fn, args), = calls
+    assert fn.__name__ == "concurrency_sweep"
+    assert (args[2] is not None) == with_ctx
+    got = jax.tree_util.tree_leaves(staged(fn, *args))
+    want = jax.tree_util.tree_leaves(jax.jit(fn)(*args))
+    assert len(got) == len(want) == 2
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

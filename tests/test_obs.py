@@ -12,9 +12,12 @@ Contracts under test:
 * the drift monitor accepts a healthy smoke-scale run, flags a
   corrupted ring, and restricts itself to conservation off the
   product-form domain;
-* the serve layer exposes the shared registry (``metrics`` verb), a
-  drift summary (``stats``), and ``repro.serve.metrics`` stays a
-  backward-compatible shim over ``repro.obs.metrics``.
+* the serve layer exposes the shared registry (``metrics`` verb) and a
+  drift summary (``stats``);
+* the registry's spans nest as the planner and the optimizer run them
+  (``suite.resolve`` / ``pack`` / ``dispatch`` / ``unpack`` inside
+  ``suite.run``; ``optimize.lower`` / ``compile`` / ``run`` inside
+  ``suite.resolve``), and each is also a profiler annotation.
 """
 import json
 import os
@@ -438,14 +441,6 @@ def test_predict_delays_profile_sums_to_m_minus_one():
 # metrics / serve integration
 # ---------------------------------------------------------------------------
 
-def test_serve_metrics_module_is_a_shim():
-    import repro.obs.metrics as obs_metrics
-    import repro.serve.metrics as serve_metrics
-
-    assert serve_metrics.Metrics is obs_metrics.Metrics
-    assert serve_metrics.Histogram is obs_metrics.Histogram
-
-
 def test_prometheus_exposition_format():
     from repro.obs.metrics import Metrics
 
@@ -480,6 +475,123 @@ def test_metrics_records_spans_for_the_host_track():
     assert rows and rows[0]["name"] == "suite.plan"
     assert rows[0]["labels"] == {"mode": "simulate"}
     assert rows[0]["duration"] >= 0.0
+
+
+def test_span_without_an_open_registry_records_nothing():
+    from repro.obs.metrics import Metrics, span
+
+    m = Metrics()
+    with span("optimize.lower", stage="x"):
+        pass
+    assert m.spans() == [] and m.snapshot()["latency"] == {}
+
+
+def test_span_records_into_the_innermost_open_registry():
+    from repro.obs.metrics import Metrics, span
+
+    outer, inner = Metrics(), Metrics()
+    with outer.timed("suite.run", mode="analyze"):
+        with span("optimize.lower"):
+            pass
+        with inner.timed("suite.resolve"):
+            with span("optimize.compile"):
+                pass
+        with span("optimize.run"):
+            pass
+    with span("optimize.lower"):  # every block closed: recorded nowhere
+        pass
+    assert [s["name"] for s in outer.spans()] == [
+        "optimize.lower", "optimize.run", "suite.run"]
+    assert [s["name"] for s in inner.spans()] == [
+        "optimize.compile", "suite.resolve"]
+    assert outer.snapshot()["latency"]["optimize.run"]["count"] == 1
+
+
+def _tiny_network(n=3, seed=21):
+    from repro.scenario import NetworkSpec
+
+    rng = np.random.default_rng(seed)
+    return NetworkSpec(mu_c=list(rng.uniform(0.8, 2.0, n)),
+                       mu_d=[4.0] * n, mu_u=[4.0] * n)
+
+
+def _tiny_time_opt_suite():
+    from repro.scenario import Scenario, ScenarioSuite, StrategySpec
+
+    scn = Scenario(network=_tiny_network(),
+                   strategy=StrategySpec("time_opt", m_max=4, steps=5),
+                   name="opt")
+    return ScenarioSuite(scn)
+
+
+def _inside(inner, outer) -> bool:
+    return (outer["start"] <= inner["start"]
+            and inner["start"] + inner["duration"]
+            <= outer["start"] + outer["duration"])
+
+
+def _in_order_without_overlap(spans) -> bool:
+    return all(a["start"] + a["duration"] <= b["start"]
+               for a, b in zip(spans, spans[1:]))
+
+
+def test_simulate_suite_records_planner_phases_in_order():
+    from repro.scenario import Scenario, ScenarioSuite, StrategySpec
+
+    net = _tiny_network()
+    suite = ScenarioSuite(
+        {f"m{m}": Scenario(network=net,
+                           strategy=StrategySpec("explicit",
+                                                 p=[1 / 3] * 3, m=m),
+                           name=f"m{m}") for m in (2, 3)},
+        seeds=(0, 1))
+    suite.run(mode="simulate", num_updates=40, warmup=5)
+    spans = suite.metrics.spans()
+    (run,) = [s for s in spans if s["name"] == "suite.run"]
+    assert run["labels"] == {"mode": "simulate"}
+    phases = sorted((s for s in spans if s["name"] != "suite.run"),
+                    key=lambda s: s["start"])
+    # one bucket: the structure planner puts alike lanes in one program
+    assert [s["name"] for s in phases] == [
+        "suite.resolve", "suite.pack", "suite.dispatch", "suite.unpack"]
+    assert all(_inside(s, run) for s in phases)
+    assert _in_order_without_overlap(phases)
+    assert all(s["labels"] == {"mode": "simulate"} for s in phases[1:])
+
+
+def test_time_opt_records_optimizer_stages_inside_resolve():
+    suite = _tiny_time_opt_suite()
+    suite.run(mode="analyze")
+    spans = suite.metrics.spans()
+    resolve = [s for s in spans if s["name"] == "suite.resolve"]
+    stages = sorted((s for s in spans if s["name"].startswith("optimize.")),
+                    key=lambda s: s["start"])
+    assert [s["name"] for s in stages] == [
+        "optimize.lower", "optimize.compile", "optimize.run"]
+    assert len(resolve) == 1 and all(_inside(s, resolve[0]) for s in stages)
+    assert _in_order_without_overlap(stages)
+    names = [s["name"] for s in sorted(spans, key=lambda s: s["start"])]
+    assert names[names.index("suite.run"):] == [
+        "suite.run", "suite.resolve", "optimize.lower", "optimize.compile",
+        "optimize.run", "suite.pack", "suite.dispatch", "suite.unpack"]
+
+
+def test_profiler_trace_holds_program_spans(tmp_path):
+    import glob
+
+    from jax.profiler import ProfileData
+
+    suite = _tiny_time_opt_suite()
+    with jax.profiler.trace(str(tmp_path)):
+        suite.run(mode="analyze")
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = {ev.name
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+    assert {"suite.run", "suite.resolve", "suite.pack", "suite.dispatch",
+            "suite.unpack", "optimize.lower", "optimize.compile",
+            "optimize.run"} <= host
 
 
 def test_server_metrics_verb_and_drift_stats(tmp_path):

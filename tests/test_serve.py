@@ -26,7 +26,7 @@ from repro.scenario import (DataSpec, LearningSpec, NetworkSpec, Scenario,
                             ScenarioSuite, StrategySpec)
 from repro.serve.batcher import MicroBatcher
 from repro.serve.client import ServeClient, ServeError
-from repro.serve.metrics import Histogram, Metrics
+from repro.obs.metrics import Histogram, Metrics
 from repro.serve.protocol import (MAX_M, WireError, encode_entry,
                                   parse_request)
 from repro.serve.server import ServeConfig, Server
