@@ -51,7 +51,7 @@ import numpy as np
 from jax.scipy.special import logsumexp
 
 from . import numerics  # noqa: F401  (enables x64)
-from .numerics import NEG_INF, seqcumsum, seqsum
+from .numerics import NEG_INF, array_module, seqcumsum, seqsum
 
 _BACKENDS = ("jnp", "pallas")
 # contract: allow(env-read): import-time default only — set_backend() overrides it at runtime, nothing caches the value
@@ -122,7 +122,8 @@ class NetworkParams(NamedTuple):
         return jnp.log(seqsum(self.gamma))
 
     def with_cs(self, mu_cs) -> "NetworkParams":
-        return self._replace(mu_cs=jnp.asarray(mu_cs, dtype=self.p.dtype))
+        xp = array_module(self.p)
+        return self._replace(mu_cs=xp.asarray(mu_cs, dtype=self.p.dtype))
 
 
 def pad_network(params: NetworkParams, n_max: int) -> NetworkParams:
@@ -137,22 +138,26 @@ def pad_network(params: NetworkParams, n_max: int) -> NetworkParams:
     ``tests/test_padded_n.py``).  Mirrors the ``m_max`` convention of
     ``repro.core.batched``: one compiled program covers a whole
     mixed-population scenario batch.
+
+    The kind of array is kept: NumPy rows pad to NumPy rows on the host
+    (the simulate planner's lane batch), anything else to ``jax.numpy``.
     """
     n = params.n
     if n_max < n:
         raise ValueError(f"n_max={n_max} is smaller than the network's "
                          f"population n={n}")
     n_act = params.active_count  # re-padding keeps the original real count
+    xp = array_module(params.p)
 
     def pad(x, fill):
-        x = jnp.asarray(x)
-        return jnp.concatenate(
-            [x, jnp.full((n_max - n,), fill, dtype=x.dtype)])
+        x = xp.asarray(x)
+        return xp.concatenate(
+            [x, xp.full((n_max - n,), fill, dtype=x.dtype)])
 
     return params._replace(
         p=pad(params.p, 0.0), mu_c=pad(params.mu_c, 1.0),
         mu_d=pad(params.mu_d, 1.0), mu_u=pad(params.mu_u, 1.0),
-        n_active=jnp.asarray(n_act, jnp.int64))
+        n_active=xp.asarray(n_act, xp.int64))
 
 
 class ClassParams(NamedTuple):
@@ -216,7 +221,8 @@ class ClassParams(NamedTuple):
         return jnp.log(seqsum(self.count.astype(self.p.dtype) * self.gamma))
 
     def with_cs(self, mu_cs) -> "ClassParams":
-        return self._replace(mu_cs=jnp.asarray(mu_cs, dtype=self.p.dtype))
+        xp = array_module(self.p)
+        return self._replace(mu_cs=xp.asarray(mu_cs, dtype=self.p.dtype))
 
     def expand(self) -> NetworkParams:
         """Unroll to the per-client network (host-side; the test oracle).
@@ -227,9 +233,10 @@ class ClassParams(NamedTuple):
         import numpy as np
 
         reps = np.asarray(self.count).astype(int)
+        xp = array_module(self.p)
 
         def rep(x):
-            return jnp.asarray(np.repeat(np.asarray(x), reps))
+            return xp.asarray(np.repeat(np.asarray(x), reps))
 
         return NetworkParams(p=rep(self.p), mu_c=rep(self.mu_c),
                              mu_d=rep(self.mu_d), mu_u=rep(self.mu_u),
@@ -244,17 +251,19 @@ def pad_classes(classes: ClassParams, c_max: int) -> ClassParams:
     event engine (the class analogue of :func:`pad_network`): a count-0
     class has the convolution-identity negative-binomial factor, adds
     exactly 0 to every sequential class reduction, and receives zero mass
-    in the routing inverse-CDF.
+    in the routing inverse-CDF.  Like :func:`pad_network`, it keeps the
+    kind of array it is given.
     """
     C = classes.C
     if c_max < C:
         raise ValueError(f"c_max={c_max} is smaller than the class-set "
                          f"size C={C}")
+    xp = array_module(classes.p)
 
     def pad(x, fill):
-        x = jnp.asarray(x)
-        return jnp.concatenate(
-            [x, jnp.full((c_max - C,), fill, dtype=x.dtype)])
+        x = xp.asarray(x)
+        return xp.concatenate(
+            [x, xp.full((c_max - C,), fill, dtype=x.dtype)])
 
     return classes._replace(
         p=pad(classes.p, 0.0), mu_c=pad(classes.mu_c, 1.0),

@@ -57,7 +57,7 @@ import numpy as np
 from . import numerics  # noqa: F401  (enables x64)
 from ..scenario.laws import get_law
 from .buzen import NetworkParams
-from .numerics import seqcumsum, seqsum
+from .numerics import array_module, seqcumsum, seqsum
 
 # task phases
 INACTIVE = -1
@@ -680,14 +680,17 @@ def unpad_stats(stats: EventStats, n: int) -> EventStats:
     unpadded ``[3n + 1]`` station layout (down / comp / up / CS).  Works on
     any number of leading lane axes.  Because trajectories are bitwise
     invariant to the padding (see :func:`_route_client`), the result equals
-    the unpadded run's statistics exactly.
+    the unpadded run's statistics exactly.  The kind of array is kept:
+    NumPy statistics (a host copy) are unpadded on the host, device arrays
+    with ``jax.numpy``.
     """
     nm = (stats.mean_queue_counts.shape[-1] - 1) // 3
     occ = stats.mean_queue_counts
+    xp = array_module(occ)
     return stats._replace(
         mean_delay=stats.mean_delay[..., :n],
         delay_counts=stats.delay_counts[..., :n],
-        mean_queue_counts=jnp.concatenate(
+        mean_queue_counts=xp.concatenate(
             [occ[..., 0:n], occ[..., nm:nm + n],
              occ[..., 2 * nm:2 * nm + n], occ[..., 3 * nm:]], axis=-1))
 

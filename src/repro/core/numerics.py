@@ -18,6 +18,19 @@ jax.config.update("jax_enable_x64", True)
 NEG_INF = -1e30  # used instead of -inf to keep gradients NaN-free
 
 
+def array_module(x):
+    """``numpy`` for a host (NumPy) array, else ``jax.numpy``: lets a
+    helper hand back the kind of array it was given (the padding helpers,
+    ``events.unpad_stats``)."""
+    import numpy as np
+
+    if isinstance(x, np.ndarray):
+        return np
+    import jax.numpy as jnp
+
+    return jnp
+
+
 def safe_log(x):
     import jax.numpy as jnp
 

@@ -269,17 +269,18 @@ class ClassSpec:
     def n_total(self) -> int:
         return int(np.asarray(self.count).sum())
 
-    def class_params(self, p=None, mu_cs=None) -> ClassParams:
+    def class_params(self, p=None, mu_cs=None, *, xp=jnp) -> ClassParams:
         """Materialize :class:`repro.core.buzen.ClassParams` (routing
-        override ``p`` > spec base ``p`` > uniform ``1/n_total``)."""
+        override ``p`` > spec base ``p`` > uniform ``1/n_total``) as
+        ``xp`` arrays (``numpy`` keeps them on the host)."""
         if p is None:
             p = (self.p if self.p is not None
                  else np.full(self.C, 1.0 / self.n_total))
         cp = ClassParams(
-            p=jnp.asarray(p, jnp.float64),
-            mu_c=jnp.asarray(self.mu_c), mu_d=jnp.asarray(self.mu_d),
-            mu_u=jnp.asarray(self.mu_u),
-            count=jnp.asarray(self.count, jnp.int64))
+            p=xp.asarray(p, xp.float64),
+            mu_c=xp.asarray(self.mu_c), mu_d=xp.asarray(self.mu_d),
+            mu_u=xp.asarray(self.mu_u),
+            count=xp.asarray(self.count, xp.int64))
         if mu_cs is not None:
             cp = cp.with_cs(mu_cs)
         return cp
@@ -370,33 +371,35 @@ class NetworkSpec:
         return (self.classes.n_total if self.classes is not None
                 else len(self.mu_c))
 
-    def params(self, p=None) -> NetworkParams:
+    def params(self, p=None, *, xp=jnp) -> NetworkParams:
         """Materialize :class:`repro.core.NetworkParams` (routing override
-        ``p`` > spec base ``p`` > uniform).
+        ``p`` > spec base ``p`` > uniform) as ``xp`` arrays (``numpy``
+        keeps them on the host).
 
         For a class network this *expands* the population (O(n) — the
         oracle path; the O(C) planner paths call :meth:`class_params`
         instead), with ``p`` interpreted per-member over classes.
         """
         if self.classes is not None:
-            return self.class_params(p).expand()
+            return self.class_params(p, xp=xp).expand()
         if p is None:
             p = self.p if self.p is not None else np.full(self.n, 1.0 / self.n)
         params = NetworkParams(
-            p=jnp.asarray(p, jnp.float64),
-            mu_c=jnp.asarray(self.mu_c), mu_d=jnp.asarray(self.mu_d),
-            mu_u=jnp.asarray(self.mu_u))
+            p=xp.asarray(p, xp.float64),
+            mu_c=xp.asarray(self.mu_c), mu_d=xp.asarray(self.mu_d),
+            mu_u=xp.asarray(self.mu_u))
         if self.mu_cs is not None:
             params = params.with_cs(self.mu_cs)
         return params
 
-    def class_params(self, p=None) -> ClassParams:
+    def class_params(self, p=None, *, xp=jnp) -> ClassParams:
         """Materialize :class:`repro.core.buzen.ClassParams` (class
-        networks only; ``p`` is per-member routing over classes)."""
+        networks only; ``p`` is per-member routing over classes) as ``xp``
+        arrays."""
         if self.classes is None:
             raise ValueError("not a class network: construct NetworkSpec "
                              "with classes= for the O(C) forms")
-        return self.classes.class_params(p, mu_cs=self.mu_cs)
+        return self.classes.class_params(p, mu_cs=self.mu_cs, xp=xp)
 
     def to_dict(self) -> dict:
         d = {"mu_c": _dict_vec(self.mu_c), "mu_d": _dict_vec(self.mu_d),

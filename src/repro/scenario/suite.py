@@ -39,6 +39,7 @@ from ..core.buzen import (NetworkParams, class_log_normalizing_constants,
                           log_normalizing_constants, pad_classes,
                           pad_network)
 from ..core.events import unpad_stats
+from ..core.numerics import array_module
 from ..core.complexity import LearningConstants, wallclock_time
 from ..core.energy import (PowerProfile, energy_optimal_routing,
                            minimal_energy)
@@ -456,7 +457,12 @@ class ScenarioSuite:
         ``suite.pack`` (lane padding and stacking, the program lookup),
         ``suite.dispatch`` (the program, to ``block_until_ready``) and
         ``suite.unpack`` (per-lane slicing and unpadding, the result
-        cache); train buckets record ``suite.dispatch`` only."""
+        cache); train buckets record ``suite.dispatch`` only.  In
+        ``simulate``, ``suite.pack`` builds the lane batch in NumPy and
+        moves it with one ``jax.device_put``, and ``suite.unpack`` fetches
+        the statistics with one ``jax.device_get`` and slices and unpads
+        on the host; the counter ``suite.transfers{mode, dir}`` counts
+        those two per bucket."""
         runners = {"analyze": self._run_analyze,
                    "simulate": self._run_simulate,
                    "train": self._run_train}
@@ -660,27 +666,12 @@ class ScenarioSuite:
                 continue
             with self.metrics.timed("suite.pack", mode="simulate"):
                 axis_max = c_max if is_classes else n_max
-                if is_classes:
-                    lane_params = _stack_params(
-                        [pad_classes(
-                            self.scenarios[n_].class_params(strategies[n_][0]),
-                            c_max)
-                         for n_, _ in todo for _ in self.seeds])
-                else:
-                    lane_params = _stack_params(
-                        [pad_network(
-                            self.scenarios[n_].params(strategies[n_][0]),
-                            n_max)
-                         for n_, _ in todo for _ in self.seeds])
-                power = (_stack_power([_pad_power(self.scenarios[n_].power(),
-                                                  axis_max)
-                                       for n_, _ in todo for _ in self.seeds])
-                         if has_power else None)
-                m_vec = jnp.asarray([strategies[n_][1]
-                                     for n_, _ in todo for _ in self.seeds],
-                                    jnp.int32)
-                keys = jnp.stack([jax.random.PRNGKey(s)
-                                  for _ in todo for s in self.seeds])
+                lanes = jax.device_put(_host_lanes(
+                    [self.scenarios[n_] for n_, _ in todo],
+                    [strategies[n_] for n_, _ in todo], self.seeds,
+                    axis_max, is_classes, has_power))
+                self.metrics.inc("suite.transfers", mode="simulate",
+                                 dir="to_device")
                 sig = ("simulate", is_classes, axis_max, law, has_cs,
                        power_sig, mx, int(num_updates), int(warmup), bk,
                        interp, tr, ck)
@@ -697,21 +688,22 @@ class ScenarioSuite:
                             chunk=ck)
                     programs += 1
             with self.metrics.timed("suite.dispatch", mode="simulate"):
-                out = jax.block_until_ready(
-                    fn(lane_params, m_vec, keys, power))
-            stats, rings = out if tr else (out, None)
+                out = jax.block_until_ready(fn(*lanes))
             self.metrics.observe("suite.lanes_per_dispatch", len(todo) * S,
                                  mode="simulate")
             with self.metrics.timed("suite.unpack", mode="simulate"):
+                # one host copy of the bucket; lanes are NumPy views of it
+                out = jax.device_get(out)
+                self.metrics.inc("suite.transfers", mode="simulate",
+                                 dir="to_host")
+                stats, rings = out if tr else (out, None)
                 for i, (name, ckey) in enumerate(todo):
                     # class lanes: statistics are per-CLASS — unpad on the
                     # class axis (expand_class_stats recovers per-member views)
                     n_i = (self.scenarios[name].network.classes.C if is_classes
                            else self.scenarios[name].n)
-                    entries[name] = [
-                        unpad_stats(jax.tree_util.tree_map(
-                            lambda a: a[i * S + j], stats), n_i)
-                        for j in range(S)]
+                    entries[name] = [unpad_stats(_lane(stats, i * S + j), n_i)
+                                     for j in range(S)]
                     self._result_cache[ckey] = entries[name]
             if not tr:
                 continue
@@ -744,10 +736,8 @@ class ScenarioSuite:
                         preds = dict(preds,
                                      delays=[float(v) for v in d])
                     self._result_cache[pkey] = preds
-                traces[name] = [
-                    decode(jax.tree_util.tree_map(
-                        lambda a: a[i * S + j], rings))
-                    for j in range(S)]
+                traces[name] = [decode(_lane(rings, i * S + j))
+                                for j in range(S)]
                 drift[name] = [
                     drift_report(d, predictions=preds, law=law,
                                  tolerance=scn.trace.tolerance)
@@ -954,14 +944,63 @@ def _stack_params(params_list) -> NetworkParams:
 
 def _pad_power(power: PowerProfile, n_max: int) -> PowerProfile:
     """Pad a power profile to ``n_max`` client rows with zero powers —
-    padded clients are never busy, so they contribute exactly 0 energy."""
+    padded clients are never busy, so they contribute exactly 0 energy.
+    Keeps the kind of array it is given, like ``pad_network``."""
+    xp = array_module(power.P_c)
+
     def pad(x):
-        x = jnp.asarray(x)
-        return jnp.concatenate(
-            [x, jnp.zeros((n_max - x.shape[0],), dtype=x.dtype)])
+        x = xp.asarray(x)
+        return xp.concatenate(
+            [x, xp.zeros((n_max - x.shape[0],), dtype=x.dtype)])
 
     return power._replace(P_c=pad(power.P_c), P_u=pad(power.P_u),
                           P_d=pad(power.P_d))
+
+
+def _lane_keys(seeds) -> np.ndarray:
+    """``[S, 2]`` uint32 lane keys on the host, bitwise
+    ``jnp.stack([jax.random.PRNGKey(s) for s in seeds])``: a threefry key
+    holds the high and the low 32-bit word of the int64 seed."""
+    s = np.asarray(seeds, np.int64)
+    return np.stack([s >> 32, s & 0xFFFFFFFF], axis=-1).astype(np.uint32)
+
+
+def _host_lanes(scenarios, strategies, seeds, axis_max: int,
+                is_classes: bool, has_power: bool) -> tuple:
+    """One simulate bucket's lane inputs ``(lane_params, m_vec, keys,
+    power)`` as NumPy arrays, lanes scenario-major then seed.
+
+    Each scenario's network is materialized and padded once, on the host,
+    and its row repeated over the seeds.  The DVFS power profile is the
+    one exception: its arithmetic runs where ``Scenario.power`` always ran
+    it, so its values stay bitwise what the device computes, and the rows
+    come back in one fetch.
+    """
+    S = len(seeds)
+
+    def repeat(rows):
+        return jax.tree_util.tree_map(
+            lambda *xs: np.repeat(np.stack(xs), S, axis=0), *rows)
+
+    if is_classes:
+        rows = [pad_classes(scn.network.class_params(p, xp=np), axis_max)
+                for scn, (p, _) in zip(scenarios, strategies)]
+    else:
+        rows = [pad_network(scn.network.params(p, xp=np), axis_max)
+                for scn, (p, _) in zip(scenarios, strategies)]
+    power = None
+    if has_power:
+        power = repeat([_pad_power(pw, axis_max) for pw in
+                        jax.device_get([scn.power() for scn in scenarios])])
+    m_vec = np.repeat(np.asarray([m for _, m in strategies], np.int32), S)
+    keys = np.tile(_lane_keys(seeds), (len(scenarios), 1))
+    return repeat(rows), m_vec, keys, power
+
+
+def _lane(tree, k: int):
+    """Lane ``k`` of a lane-stacked pytree (``[k, ...]`` keeps a NumPy
+    leaf an array, never a scalar)."""
+    return jax.tree_util.tree_map(lambda a: a[k, ...], tree)
 
 
 def _stack_consts(consts_list) -> LearningConstants:
