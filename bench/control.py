@@ -2,6 +2,8 @@
 
     python3 bench/control.py --workload table1.sim-msweep \
         --seeds 11 12 13 --requests 1 --control f32-reference
+    python3 bench/control.py --workload class1m.sim-m1000 \
+        --seeds 11 12 13 --requests 1 --control f32-reference
     python3 bench/control.py --workload table1.analyze-timeopt \
         --seeds 11 12 13 --control pallas
 
@@ -11,8 +13,9 @@ program against the reference (the lower reading); ``control`` is the
 same comparison with the control in the program's place (the upper
 reading):
 
-* ``f32-reference``: the plain event reference with a float32 clock in
-  place of the program (simulate cells);
+* ``f32-reference``: the plain event reference of the fleet's kind
+  (client by client, or class by class) with a float32 clock in place of
+  the program (simulate cells);
 * ``pallas``: the program itself with its float32 Buzen kernel switched
   on (``REPRO_BUZEN_BACKEND=pallas``; analyze cells).
 

@@ -10,20 +10,21 @@ so the lane program stays resident.
 
 Correctness: once the window has closed, a sample of the window's lanes
 drawn from the seed (for every ``m``, ``lanes_per_concurrency`` distinct
-lanes of one request) is run again by the plain reference
-(``bench/reference/events_ref.py``) from the same lane seed, and the two
-sets of statistics are compared:
+lanes of one request) is run again by the plain reference of the fleet's
+kind (``bench/reference/events_ref.py`` client by client,
+``events_class_ref.py`` class by class) from the same lane seed, and the
+two sets of statistics are compared:
 ``count_mismatch`` counts integer statistics that differ (updates, updates
 per client), ``stats_gap`` is the worst relative gap of the float
-statistics (time, throughput, mean delay per client, mean station
-occupancy), each leaf by its largest entry.
+statistics (time, throughput, mean delay per client or class, mean
+station occupancy), each leaf by its largest entry.
 """
 from __future__ import annotations
 
 import numpy as np
 
 from bench import fleet
-from bench.reference import events_ref
+from bench.reference import events_class_ref, events_ref
 
 FLOAT_LEAVES = ("time", "throughput", "mean_delay", "mean_queue_counts")
 INT_LEAVES = ("updates", "delay_counts")
@@ -33,6 +34,14 @@ def lane_seeds(seed: int, index: int, count: int) -> list:
     """``count`` lane seeds of request ``index`` (``0`` is the warm-up)."""
     ss = np.random.SeedSequence([int(seed) & (2**64 - 1), int(index)])
     return [int(s) for s in ss.generate_state(count, np.uint32)]
+
+
+def reference(config: dict) -> tuple:
+    """``(replay, fleet arrays)``: the plain lane replay of the
+    configuration's fleet kind and the fleet as that replay reads it."""
+    if config["fleet"] == "classes":
+        return events_class_ref.lane_stats, fleet.class_arrays(config)
+    return events_ref.lane_stats, fleet.arrays(config)
 
 
 def leaf_gap(prog, ref) -> float:
@@ -112,7 +121,7 @@ class Mode:
         t = self.traffic
         self.caches = None  # the program's state goes before the reference
         rng = np.random.default_rng([self.seed & (2**64 - 1), 1])
-        arrays = fleet.arrays(self.config)
+        replay, arrays = reference(self.config)
         p = fleet.uniform_routing(self.config)
         done = run.done
         mismatch, gap = 0, 0.0
@@ -123,9 +132,9 @@ class Mode:
             for k in picks:
                 args = (arrays, p, int(m), int(t["m_max"]), seeds[k],
                         int(t["warmup"]), int(t["updates"]))
-                ref = events_ref.lane_stats(*args)
+                ref = replay(*args)
                 if control:
-                    prog = events_ref.lane_stats(*args, dtype=np.float32)
+                    prog = replay(*args, dtype=np.float32)
                 else:
                     stats = entries[f"m{m}"][k]
                     prog = {f: np.asarray(getattr(stats, f))

@@ -2,10 +2,11 @@
 
 The control is the plain reference put in the program's place, computed
 one precision below the configuration's float64: a float32 clock for the
-event dynamics, float32 closed forms and search for ``time_opt``.  The
-runs are at the cells' own sizes; only the lanes and concurrencies the
-check would draw are fewer.  (On the chip the analyze cell's control is
-the program's own float32 Buzen kernel; its readings are in PERF.md.)
+event dynamics (client by client, or class by class), float32 closed
+forms and search for ``time_opt``.  The runs are at the cells' own sizes;
+only the lanes and concurrencies the check would draw are fewer.  (On the
+chip the analyze cell's control is the program's own float32 Buzen
+kernel; its readings are in PERF.md.)
 """
 import json
 import os
@@ -15,8 +16,8 @@ import pytest
 
 from bench import fleet, harness
 from bench.modes.analyze import LEAVES
-from bench.modes.simulate import compare, leaf_gap
-from bench.reference import closed_forms, events_ref
+from bench.modes.simulate import compare, leaf_gap, reference
+from bench.reference import closed_forms
 
 
 def _cell(name):
@@ -25,14 +26,14 @@ def _cell(name):
 
 
 @pytest.mark.parametrize("seed", [3, 2**31 + 7, 987654321])
-def test_float32_clock_fails_the_simulate_limits(seed):
-    config, t, limits = _cell("table1.sim-msweep")
-    args = (fleet.arrays(config), fleet.uniform_routing(config),
-            max(t["concurrency"]), t["m_max"], seed, t["warmup"],
-            t["updates"])
-    ref = events_ref.lane_stats(*args)
-    mismatch, gap = compare(events_ref.lane_stats(*args, dtype=np.float32),
-                            ref)
+@pytest.mark.parametrize("workload", ["table1.sim-msweep",
+                                      "class1m.sim-m1000"])
+def test_float32_clock_fails_the_simulate_limits(workload, seed):
+    config, t, limits = _cell(workload)
+    replay, arrays = reference(config)
+    args = (arrays, fleet.uniform_routing(config), max(t["concurrency"]),
+            t["m_max"], seed, t["warmup"], t["updates"])
+    mismatch, gap = compare(replay(*args, dtype=np.float32), replay(*args))
     assert mismatch > limits["count_mismatch"] or gap > limits["stats_gap"]
 
 
@@ -59,6 +60,7 @@ def test_limits_sit_between_the_readings():
     smallest control reading measured on the chip (PERF.md, §4)."""
     readings = {  # (largest sound, smallest control) on a TPU v5e
         "sim-msweep": {"stats_gap": (2.9e-11, 0.38)},
+        "sim-m1000": {"stats_gap": (2.6e-13, 0.018)},
         "analyze-timeopt": {"closed_form_gap": (2.3e-8, 2.5e-4),
                             "opt_gap": (1.6e-8, 1.6e-4)},
     }
